@@ -35,8 +35,10 @@ def main() -> None:
         bench_bc, bench_bc_distribution, bench_kernels, bench_moe_glb,
         bench_params, bench_serve, bench_uts,
     )
+    from repro.launch.compile_cache import enable_compile_cache
     from repro.obs import clock_sync
 
+    enable_compile_cache()
     modules = [
         ("uts_scaling", bench_uts),
         ("bc_scaling", bench_bc),

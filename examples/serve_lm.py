@@ -55,6 +55,7 @@ import time
 import jax
 
 from repro.configs import ARCHS
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_lm
 from repro.obs import (FlightRecorder, SLOMonitor, Tracer, analyze_trace,
                        parse_slo_spec, render_summary,
@@ -110,6 +111,7 @@ def main():
                          "greedy tokens (requires --paged, >= 2 "
                          "replicas)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = ARCHS["tinyllama-1.1b"].smoke()
     params = init_lm(jax.random.key(0), cfg)

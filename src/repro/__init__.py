@@ -3,8 +3,6 @@
 The paper's contribution lives in repro.core; its workloads in
 repro.problems; the LM training/serving stack that hosts the technique as a
 first-class feature (MoE expert placement, serving-replica balancing) in
-the sibling subpackages. See DESIGN.md / EXPERIMENTS.md at the repo root.
+the sibling subpackages. See DESIGN.md at the repo root.
 """
-from . import _jaxcompat  # noqa: F401  (backfills modern jax APIs on 0.4.x)
-
 __version__ = "1.0.0"

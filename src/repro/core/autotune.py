@@ -45,7 +45,7 @@ def decode_block_k(kv_len: int, head_dim: int) -> int:
 # paged kernel streams exactly one pool block per grid step, so this is
 # both the allocator granularity and the kernel tile: small enough that
 # internal fragmentation (the partially-filled tail block per sequence)
-# stays low at production request lengths, large enough that the (1, D) x
+# stays low at production request lengths, large enough that the (group, D) x
 # (Bs, D)^T step keeps the MXU lanes busy and the per-block DMA amortizes.
 # 4-8x smaller than DECODE_BLOCK_K — the contiguous kernel pays
 # fragmentation at *bucket* granularity instead, so it wants big tiles.

@@ -243,35 +243,27 @@ def cache_axes(cfg) -> Any:
 
 
 # ----------------------------------------------------------- activations
-_REAL_MESH_TYPES = tuple(
-    t for t in (
-        getattr(jax.sharding, "Mesh", None),
-        getattr(jax.sharding, "AbstractMesh", None),
-    ) if t is not None
-)
-
-
-def _ambient_mesh():
-    try:
-        m = jax.sharding.get_abstract_mesh()
-    except Exception:  # noqa: BLE001 - no ambient-mesh API / no context
-        return None
-    if m is None or not getattr(m, "shape", None):
-        return None
-    return m
+def ambient_mesh():
+    """The mesh set by ``jax.sharding.set_mesh``, or None outside one
+    (``get_abstract_mesh`` then returns an empty mesh)."""
+    m = jax.sharding.get_abstract_mesh()
+    return m if m.shape else None
 
 
 def shard_act(x, *axes: str):
-    """``with_sharding_constraint`` resolved through the rule engine.
+    """Shard an activation as the rule engine resolves its logical axes.
 
     Safely a no-op when called outside any mesh context (unit tests, eager
-    CPU runs) — model code calls this unconditionally from scan bodies."""
-    mesh = _ambient_mesh()
+    CPU runs) — model code calls this unconditionally from scan bodies.
+    Under a mesh whose axes are all ``Explicit`` (the default of
+    ``jax.make_mesh``) ``with_sharding_constraint`` only asserts the
+    sharding, so the array is moved with ``reshard`` instead."""
+    mesh = ambient_mesh()
     if mesh is None:
         return x
     # a typo'd logical axis or rank mismatch is a caller bug and must raise,
     # not silently drop the constraint
-    spec = spec_for(tuple(x.shape), axes, mesh)
-    if not isinstance(mesh, _REAL_MESH_TYPES):
-        return x  # test fakes: resolvable but not constrainable
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    sharding = NamedSharding(mesh, spec_for(tuple(x.shape), axes, mesh))
+    if all(t == jax.sharding.AxisType.Explicit for t in mesh.axis_types):
+        return jax.sharding.reshard(x, sharding)
+    return jax.lax.with_sharding_constraint(x, sharding)

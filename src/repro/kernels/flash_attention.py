@@ -9,8 +9,14 @@ Supports Sq != Skv with decode alignment (query i sits at absolute position
 Skv - Sq + i), which is what the serving path needs (Sq == 1 against a long
 KV cache), and GQA via the kv-head index map (h // group).
 
-Oracle: ref.attention_ref. Validated in interpret mode on CPU; compiled on
-TPU (MXU-aligned tiles: block_q/block_k multiples of 128 when shapes allow).
+Layout: the kernel runs head-major — q (B, Hq, Sq, D), k/v (B, Hkv, Skv, D)
+— so every block ends in (block_tokens, D), the (8, 128)-tileable shape the
+TPU compiler requires; the wrapper transposes from and back to the model's
+(B, S, H, D). ``online_softmax_step`` is the per-block update shared with
+the decode and paged kernels.
+
+Oracle: ref.attention_ref. Validated in interpret mode on CPU; compiled for
+TPU by tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -25,49 +31,72 @@ from jax.experimental import pallas as pl
 NEG_INF = -1e30
 
 
+def online_softmax_step(q, k, v, live, acc_ref, m_ref, l_ref, scale):
+    """Fold one kv block into the running (acc, m, l) VMEM scratch.
+
+    q (R, D), k/v (Bk, D) f32; live (R, Bk) bool marks visible keys. Dead
+    keys get p == 0, so a row whose keys are all dead keeps its (m, l)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+    ) * scale                                               # (R, Bk)
+    s = jnp.where(live, s, NEG_INF)
+    m_prev = m_ref[...]                                     # (R, 1)
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.where(live, jnp.exp(s - m_new), 0.0)
+    alpha = jnp.exp(m_prev - m_new)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+    )
+    m_ref[...] = m_new
+    l_ref[...] = alpha * l_ref[...] + p.sum(axis=-1, keepdims=True)
+
+
+def softmax_scratch(rows, D):
+    """VMEM scratch carried across the sequential kv grid dimension."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [
+        pltpu.VMEM((rows, D), jnp.float32),   # output accumulator
+        pltpu.VMEM((rows, 1), jnp.float32),   # running max
+        pltpu.VMEM((rows, 1), jnp.float32),   # running denominator
+    ]
+
+
+def init_scratch(acc_ref, m_ref, l_ref):
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def finish(acc_ref, l_ref):
+    """acc / l, with fully-masked rows (l == 0, acc == 0) emitting zeros."""
+    l = l_ref[...]
+    return acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
             scale, causal, sq, skv, block_q, block_k, num_kv):
+    i = pl.program_id(2)
     j = pl.program_id(3)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    i = pl.program_id(2)
+        init_scratch(acc_ref, m_ref, l_ref)
 
     def _body():
-        qpos = i * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 0
-        ) + (skv - sq)
-        kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (block_q, block_k), 1
-        )
-        q = q_ref[0, :, 0, :].astype(jnp.float32)           # (Bq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)           # (Bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)           # (Bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                           # (Bq, Bk)
+        q = q_ref[0, 0].astype(jnp.float32)                 # (Bq, D)
+        k = k_ref[0, 0].astype(jnp.float32)                 # (Bk, D)
+        v = v_ref[0, 0].astype(jnp.float32)
+        live = jnp.ones((block_q, block_k), jnp.bool_)
         if causal:
-            mask = qpos >= kpos
-            s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[...]                                 # (Bq, 1)
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev[:, 0], s.max(axis=-1))   # (Bq,)
-        p = jnp.exp(s - m_new[:, None])
-        if causal:
-            p = jnp.where(qpos >= kpos, p, 0.0)
-        alpha = jnp.exp(m_prev[:, 0] - m_new)               # (Bq,)
-        l_new = alpha * l_prev[:, 0] + p.sum(axis=-1)
-        acc_ref[...] = acc_ref[...] * alpha[:, None] + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new[:, None]
-        l_ref[...] = l_new[:, None]
+            qpos = i * block_q + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 0
+            ) + (skv - sq)
+            kpos = j * block_k + jax.lax.broadcasted_iota(
+                jnp.int32, (block_q, block_k), 1
+            )
+            live = qpos >= kpos
+        online_softmax_step(q, k, v, live, acc_ref, m_ref, l_ref, scale)
 
     if causal:
         # Block-level causal skip: a kv block whose first key position lies
@@ -75,16 +104,13 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
         # contributes nothing — pl.when drops its matmuls/iota entirely
         # (~2x fewer FLOPs on square causal prefill).
         q_max = i * block_q + block_q - 1 + (skv - sq)
-        k_min = j * block_k
-        pl.when(q_max >= k_min)(_body)
+        pl.when(q_max >= j * block_k)(_body)
     else:
         _body()
 
     @pl.when(j == num_kv - 1)
     def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = finish(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -111,27 +137,18 @@ def flash_attention(
         _kernel, scale=scale, causal=causal, sq=Sq, skv=Skv,
         block_q=bq, block_k=bk, num_kv=num_kv,
     )
-    return pl.pallas_call(
+    heads_first = lambda x: jnp.swapaxes(x, 1, 2)
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // group, 0)),
-            pl.BlockSpec((1, bk, 1, D), lambda b, h, i, j: (b, j, h // group, 0)),
+            pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // group, j, 0)),
+            pl.BlockSpec((1, 1, bk, D), lambda b, h, i, j: (b, h // group, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bq, 1, D), lambda b, h, i, j: (b, i, h, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        # VMEM scratch carried across the sequential kv grid dimension.
-        scratch_shapes=[
-            _vmem((bq, D), jnp.float32),   # output accumulator
-            _vmem((bq, 1), jnp.float32),   # running max
-            _vmem((bq, 1), jnp.float32),   # running denominator
-        ],
+        out_specs=pl.BlockSpec((1, 1, bq, D), lambda b, h, i, j: (b, h, i, 0)),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
+        scratch_shapes=softmax_scratch(bq, D),
         interpret=interpret,
-    )(q, k, v)
-
-
-def _vmem(shape, dtype):
-    from jax.experimental.pallas import tpu as pltpu
-
-    return pltpu.VMEM(shape, dtype)
+    )(heads_first(q), heads_first(k), heads_first(v))
+    return heads_first(out)

@@ -2,11 +2,13 @@
 KV cache, specialized for the serving hot path (Sq == 1).
 
 The prefill kernel (flash_attention.py) tiles queries and keys; at decode
-there is exactly one query row per (batch, head), so the grid becomes
-(batch, q_heads, kv_blocks) with the KV dimension innermost — TPU grids
-execute sequentially, so the online-softmax partials (running max m,
-denominator l, weighted accumulator acc) live in VMEM scratch across KV
-steps and the (1, D) output tile is written once on the last step.
+there is exactly one query token per (batch, head), so each grid step
+takes the ``group`` query heads that share one kv head as a (group, D)
+block: the grid becomes (batch, kv_heads, kv_blocks) with the KV dimension
+innermost — TPU grids execute sequentially, so the online-softmax partials
+(running max m, denominator l, weighted accumulator acc) live in VMEM
+scratch across KV steps and the (group, D) output tile is written once on
+the last step.
 
 Per-slot cache lengths arrive as a scalar-prefetch operand
 (PrefetchScalarGridSpec), so they gate the kernel at three levels:
@@ -20,10 +22,13 @@ Per-slot cache lengths arrive as a scalar-prefetch operand
   * lane mask   — the partial tail block masks key positions >= window
     before the softmax.
 
-GQA rides on the kv-head index map (h // group), same as the prefill
-kernel. Oracle: ref.attention_ref on the visible window (ref.decode_ref is
-the padded-cache form). Validated in interpret mode on CPU; block sizes
-for TPU come from core.autotune.DECODE_BLOCK_K.
+Layout: the kernel reads the cache head-major, (B, Hkv, S, D), so each
+block ends in (block_k, D) as the TPU compiler requires; the wrapper
+transposes the model's (B, S, Hkv, D) cache on the way in. ``decode_kernel``
+is shared with the paged kernel (paged_decode.py), which differs only in
+its index maps. Oracle: ref.attention_ref on the visible window
+(ref.decode_ref is the padded-cache form). Block sizes come from
+core.autotune.DECODE_BLOCK_K.
 """
 from __future__ import annotations
 
@@ -34,54 +39,38 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-NEG_INF = -1e30
+from .flash_attention import (finish, init_scratch, online_softmax_step,
+                              softmax_scratch)
 
 
-def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                   l_ref, *, scale, block_k, num_kv):
+def decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
+                  l_ref, *, scale, block_k, num_kv):
+    """One (slot, kv head, kv block) step: q (group, D) vs k/v (block_k,
+    D). ``len_ref`` holds each slot's visible window (0 => idle)."""
     b = pl.program_id(0)
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
-    n = len_ref[b]  # visible KV entries for this slot; 0 => idle
+    n = len_ref[b]
 
     @pl.when(j * block_k < n)  # skip past-window blocks and idle slots
     def _body():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32)[None, :]      # (1, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)               # (Bk, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)               # (Bk, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                               # (1, Bk)
+        q = q_ref[0, 0].astype(jnp.float32)                     # (G, D)
         kpos = j * block_k + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_k), 1
+            jnp.int32, (q.shape[0], block_k), 1
         )
-        live = kpos < n
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[...]                                     # (1, 1)
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(live, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)                         # (1, 1)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        online_softmax_step(
+            q, k_ref[0, 0].astype(jnp.float32),
+            v_ref[0, 0].astype(jnp.float32), kpos < n,
+            acc_ref, m_ref, l_ref, scale,
         )
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
 
     @pl.when(j == num_kv - 1)
     def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # idle slot: acc == 0 -> output 0
-        o_ref[0, 0, 0, :] = (acc_ref[...] / l)[0].astype(o_ref.dtype)
+        o_ref[0, 0] = finish(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -115,32 +104,28 @@ def flash_decode(q, k, v, lengths, *, scale: float | None = None,
         # pipeline skips the DMA for a repeated index, and pl.when skips
         # the compute, so dead cache rows are neither fetched nor read.
         last = jnp.maximum(lens[b] - 1, 0) // bk
-        return (b, jnp.minimum(j, last), h // group, 0)
+        return (b, h, jnp.minimum(j, last), 0)
 
+    q_map = lambda b, h, j, lens: (b, h, 0, 0)
     kernel = functools.partial(
-        _decode_kernel, scale=scale, block_k=bk, num_kv=num_kv
+        decode_kernel, scale=scale, block_k=bk, num_kv=num_kv
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(B, Hq, num_kv),
+        grid=(B, Hkv, num_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, D), lambda b, h, j, lens: (b, 0, h, 0)),
-            pl.BlockSpec((1, bk, 1, D), kv_map),
-            pl.BlockSpec((1, bk, 1, D), kv_map),
+            pl.BlockSpec((1, 1, group, D), q_map),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
+            pl.BlockSpec((1, 1, bk, D), kv_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, D), lambda b, h, j, lens: (b, 0, h, 0)
-        ),
-        # VMEM scratch carried across the sequential kv grid dimension.
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running denominator
-        ],
+        out_specs=pl.BlockSpec((1, 1, group, D), q_map),
+        scratch_shapes=softmax_scratch(group, D),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
-    )(lens, q, k, v)
+    )(lens, q.reshape(B, Hkv, group, D), jnp.swapaxes(k, 1, 2),
+      jnp.swapaxes(v, 1, 2))
+    return out.reshape(B, 1, Hq, D)

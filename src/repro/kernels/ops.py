@@ -1,7 +1,7 @@
 """Dispatch layer: jit'd public entry points that pick the Pallas kernel on
-TPU and the pure-jnp oracle elsewhere (this container is CPU-only; kernels
-are validated in interpret mode by the test suite, the models call through
-here so a TPU deployment gets the kernels with zero code change).
+TPU and the pure-jnp oracle elsewhere (on CPU the test suite validates the
+kernels in interpret mode; the models call through here, so a TPU run gets
+the kernels with zero code change).
 """
 from __future__ import annotations
 
@@ -33,7 +33,7 @@ def attention(q, k, v, *, causal=True, scale=None, impl: str = "auto",
     oracle elsewhere; the decode_* impls force one arm.
 
     `block_tables` ((B, max_blocks) i32) additionally marks k/v as flat
-    (num_blocks, block_size, Hkv, D) KV *pools* indirected per sequence
+    (num_blocks, Hkv, block_size, D) KV *pools* indirected per sequence
     through the table (serve/kvpool.py): calls route to the paged
     flash-decode kernel on TPU and the gather oracle elsewhere. Every
     impl spelling is normalized so one config knob drives contiguous and

@@ -22,21 +22,23 @@ the contiguous kernel applies:
     window (idle slots, window == 0, skip everything and emit zeros);
   * lane mask   — the partial tail block masks key positions >= window.
 
-The KV block size is the pool's block size (one pool block per grid
-step), chosen by ``core.autotune.paged_block_kv``; GQA rides on the
-kv-head index map (h // group) as everywhere else. Oracle:
-``ref.paged_decode_ref`` (gather blocks -> decode_ref). Routed via
-``ops.attention(..., block_tables=...)``; validated in interpret mode on
-CPU.
+The pool is head-major, (num_blocks, Hkv, Bs, D), so one grid step reads
+a (Bs, D) block of one kv head — a shape the TPU compiler tiles for any
+Bs and D. The KV block size is the pool's block size (one pool block per
+grid step), chosen by ``core.autotune.paged_block_kv``. Decode runs the
+contiguous kernel's body (flash_decode.decode_kernel): each step takes the
+``group`` query heads of one kv head as a (group, D) block, so the grid
+is (batch, kv_heads, max_blocks). Oracle: ``ref.paged_decode_ref``
+(gather blocks -> decode_ref). Routed via
+``ops.attention(..., block_tables=...)``.
 
-``paged_prefill`` generalizes the decode kernel to an (Sq, D) query
-block and a third scalar-prefetch operand ``q_offset`` ((B,) i32 chunk
-start): the mask becomes causal-by-absolute-position
-(``kpos <= q_offset + i``) intersected with the ``lengths`` window, the
-online-softmax scratch grows to (Sq, 1)/(Sq, D), and everything else —
-table walk, DMA clamp, block skip, lane mask, GQA — is unchanged.
-Oracle: ``ref.paged_prefill_ref``; routed via
-``ops.attention(..., block_tables=..., q_offset=...)``.
+``paged_prefill`` generalizes the walk to an (Sq, D) query block of one
+query head (grid (batch, q_heads, max_blocks), kv head h // group) and a
+third scalar-prefetch operand ``q_offset`` ((B,) i32 chunk start): the
+mask becomes causal-by-absolute-position (``kpos <= q_offset + i``)
+intersected with the ``lengths`` window; table walk, DMA clamp, block
+skip and lane mask are unchanged. Oracle: ``ref.paged_prefill_ref``;
+routed via ``ops.attention(..., block_tables=..., q_offset=...)``.
 """
 from __future__ import annotations
 
@@ -47,60 +49,24 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
-NEG_INF = -1e30
+from .flash_attention import (finish, init_scratch, online_softmax_step,
+                              softmax_scratch)
+from .flash_decode import decode_kernel
 
 
-def _paged_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, acc_ref,
-                  m_ref, l_ref, *, scale, block_size, max_blocks):
-    b = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-
-    n = len_ref[b]  # visible window (tokens) for this slot; 0 => idle
-
-    @pl.when(j * block_size < n)  # skip past-window blocks and idle slots
-    def _body():
-        q = q_ref[0, 0, 0, :].astype(jnp.float32)[None, :]      # (1, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)               # (Bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)               # (Bs, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                               # (1, Bs)
-        kpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, block_size), 1
-        )
-        live = kpos < n
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[...]                                     # (1, 1)
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(live, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)                         # (1, 1)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
-
-    @pl.when(j == max_blocks - 1)
-    def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # idle slot: acc == 0 -> output 0
-        o_ref[0, 0, 0, :] = (acc_ref[...] / l)[0].astype(o_ref.dtype)
+def _walk(bt, lens, b, j, Bs, NB):
+    """Physical pool block for grid step j of slot b. Past-window logical
+    blocks clamp to the last live one so the physical index repeats (no
+    DMA, compute skipped by pl.when); unallocated/garbage table entries
+    are clamped into the pool so the address is always valid."""
+    last = jnp.maximum(lens[b] - 1, 0) // Bs
+    return jnp.clip(bt[b, jnp.minimum(j, last)], 0, NB - 1)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
                  scale: float | None = None, interpret: bool = False):
-    """q: (B, 1, Hq, D); k_pool, v_pool: (num_blocks, Bs, Hkv, D) flat
+    """q: (B, 1, Hq, D); k_pool, v_pool: (num_blocks, Hkv, Bs, D) flat
     block pools; block_tables: (B, max_blocks) i32 physical block per
     logical block (entries past the allocation may be any value — they
     are clamped away); lengths: (B,) i32 visible window (0 => idle slot,
@@ -108,7 +74,7 @@ def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, Sq, Hq, D = q.shape
-    NB, Bs, Hkv, _ = k_pool.shape
+    NB, Hkv, Bs, _ = k_pool.shape
     assert Sq == 1, f"paged_decode is Sq==1 only, got {Sq}"
     assert Hq % Hkv == 0, (Hq, Hkv)
     group = Hq // Hkv
@@ -118,42 +84,30 @@ def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
 
     def kv_map(b, h, j, bt, lens):
-        # Walk the block table. Past-window logical blocks clamp to the
-        # last live one so the physical index repeats (no DMA, compute
-        # skipped by pl.when); unallocated/garbage table entries are
-        # clamped into the pool so the address is always valid.
-        last = jnp.maximum(lens[b] - 1, 0) // Bs
-        phys = bt[b, jnp.minimum(j, last)]
-        return (jnp.clip(phys, 0, NB - 1), 0, h // group, 0)
+        return (_walk(bt, lens, b, j, Bs, NB), h, 0, 0)
 
+    q_map = lambda b, h, j, bt, lens: (b, h, 0, 0)
     kernel = functools.partial(
-        _paged_kernel, scale=scale, block_size=Bs, max_blocks=max_blocks
+        decode_kernel, scale=scale, block_k=Bs, num_kv=max_blocks
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(B, Hq, max_blocks),
+        grid=(B, Hkv, max_blocks),
         in_specs=[
-            pl.BlockSpec((1, 1, 1, D),
-                         lambda b, h, j, bt, lens: (b, 0, h, 0)),
-            pl.BlockSpec((1, Bs, 1, D), kv_map),
-            pl.BlockSpec((1, Bs, 1, D), kv_map),
+            pl.BlockSpec((1, 1, group, D), q_map),
+            pl.BlockSpec((1, 1, Bs, D), kv_map),
+            pl.BlockSpec((1, 1, Bs, D), kv_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, 1, 1, D), lambda b, h, j, bt, lens: (b, 0, h, 0)
-        ),
-        # VMEM scratch carried across the sequential block-walk dimension.
-        scratch_shapes=[
-            pltpu.VMEM((1, D), jnp.float32),   # output accumulator
-            pltpu.VMEM((1, 1), jnp.float32),   # running max
-            pltpu.VMEM((1, 1), jnp.float32),   # running denominator
-        ],
+        out_specs=pl.BlockSpec((1, 1, group, D), q_map),
+        scratch_shapes=softmax_scratch(group, D),
     )
-    return pl.pallas_call(
-        kernel,
+    out = pl.pallas_call(
+        lambda bt_ref, *refs: kernel(*refs),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
         interpret=interpret,
-    )(bt, lens, q, k_pool, v_pool)
+    )(bt, lens, q.reshape(B, Hkv, group, D), k_pool, v_pool)
+    return out.reshape(B, 1, Hq, D)
 
 
 def _paged_prefill_kernel(bt_ref, len_ref, off_ref, q_ref, k_ref, v_ref,
@@ -164,22 +118,13 @@ def _paged_prefill_kernel(bt_ref, len_ref, off_ref, q_ref, k_ref, v_ref,
 
     @pl.when(j == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
     n = len_ref[b]      # visible window: q_offset + true chunk length
     off = off_ref[b]    # absolute position of query row 0
 
     @pl.when(j * block_size < n)  # skip blocks wholly past the window
     def _body():
-        q = q_ref[0, :, 0, :].astype(jnp.float32)               # (Sq, D)
-        k = k_ref[0, :, 0, :].astype(jnp.float32)               # (Bs, D)
-        v = v_ref[0, :, 0, :].astype(jnp.float32)               # (Bs, D)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale                                               # (Sq, Bs)
         kpos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (sq, block_size), 1
         )
@@ -188,27 +133,17 @@ def _paged_prefill_kernel(bt_ref, len_ref, off_ref, q_ref, k_ref, v_ref,
         )
         # Causal by absolute position, clamped to the window; a kv block
         # entirely after some query row leaves that row's lane mask all
-        # dead — p is re-zeroed below so its (m, l) stay untouched.
-        live = (kpos <= qpos) & (kpos < n)
-        s = jnp.where(live, s, NEG_INF)
-        m_prev = m_ref[...]                                     # (Sq, 1)
-        l_prev = l_ref[...]
-        m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        p = jnp.where(live, p, 0.0)
-        alpha = jnp.exp(m_prev - m_new)                         # (Sq, 1)
-        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
+        # dead, so its (m, l) stay untouched.
+        online_softmax_step(
+            q_ref[0, 0].astype(jnp.float32),
+            k_ref[0, 0].astype(jnp.float32),
+            v_ref[0, 0].astype(jnp.float32),
+            (kpos <= qpos) & (kpos < n), acc_ref, m_ref, l_ref, scale,
         )
-        m_ref[...] = m_new
-        l_ref[...] = alpha * l_prev + p.sum(axis=-1, keepdims=True)
 
     @pl.when(j == max_blocks - 1)
     def _finish():
-        l = l_ref[...]
-        l = jnp.where(l == 0.0, 1.0, l)  # fully-masked row -> output 0
-        o_ref[0, :, 0, :] = (acc_ref[...] / l).astype(o_ref.dtype)
+        o_ref[0, 0] = finish(acc_ref, l_ref).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -223,7 +158,7 @@ def paged_prefill(q, k_pool, v_pool, block_tables, lengths, q_offset, *,
     from jax.experimental.pallas import tpu as pltpu
 
     B, Sq, Hq, D = q.shape
-    NB, Bs, Hkv, _ = k_pool.shape
+    NB, Hkv, Bs, _ = k_pool.shape
     assert Hq % Hkv == 0, (Hq, Hkv)
     group = Hq // Hkv
     max_blocks = block_tables.shape[1]
@@ -233,12 +168,9 @@ def paged_prefill(q, k_pool, v_pool, block_tables, lengths, q_offset, *,
     offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))
 
     def kv_map(b, h, j, bt, lens, offs):
-        # Same walk/clamp as decode: past-window logical blocks repeat
-        # the last live physical block (no DMA, compute skipped).
-        last = jnp.maximum(lens[b] - 1, 0) // Bs
-        phys = bt[b, jnp.minimum(j, last)]
-        return (jnp.clip(phys, 0, NB - 1), 0, h // group, 0)
+        return (_walk(bt, lens, b, j, Bs, NB), h // group, 0, 0)
 
+    q_map = lambda b, h, j, bt, lens, offs: (b, h, 0, 0)
     kernel = functools.partial(
         _paged_prefill_kernel, scale=scale, block_size=Bs,
         max_blocks=max_blocks, sq=Sq,
@@ -247,23 +179,17 @@ def paged_prefill(q, k_pool, v_pool, block_tables, lengths, q_offset, *,
         num_scalar_prefetch=3,
         grid=(B, Hq, max_blocks),
         in_specs=[
-            pl.BlockSpec((1, Sq, 1, D),
-                         lambda b, h, j, bt, lens, offs: (b, 0, h, 0)),
-            pl.BlockSpec((1, Bs, 1, D), kv_map),
-            pl.BlockSpec((1, Bs, 1, D), kv_map),
+            pl.BlockSpec((1, 1, Sq, D), q_map),
+            pl.BlockSpec((1, 1, Bs, D), kv_map),
+            pl.BlockSpec((1, 1, Bs, D), kv_map),
         ],
-        out_specs=pl.BlockSpec(
-            (1, Sq, 1, D), lambda b, h, j, bt, lens, offs: (b, 0, h, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((Sq, D), jnp.float32),   # output accumulator
-            pltpu.VMEM((Sq, 1), jnp.float32),   # running max
-            pltpu.VMEM((Sq, 1), jnp.float32),   # running denominator
-        ],
+        out_specs=pl.BlockSpec((1, 1, Sq, D), q_map),
+        scratch_shapes=softmax_scratch(Sq, D),
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, Hq, Sq, D), q.dtype),
         interpret=interpret,
-    )(bt, lens, offs, q, k_pool, v_pool)
+    )(bt, lens, offs, jnp.swapaxes(q, 1, 2), k_pool, v_pool)
+    return jnp.swapaxes(out, 1, 2)

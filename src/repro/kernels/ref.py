@@ -78,14 +78,21 @@ def paged_decode_ref(q, k_pool, v_pool, block_tables, lengths,
     entries past the allocation are clamped into the pool — the window
     mask keeps their rows invisible.
 
-    q (B,1,Hq,hd); k_pool/v_pool (num_blocks, Bs, Hkv, hd);
+    q (B,1,Hq,hd); k_pool/v_pool (num_blocks, Hkv, Bs, hd);
     block_tables (B, max_blocks) i32; lengths (B,) i32."""
-    B = q.shape[0]
-    NB, Bs, Hkv, hd = k_pool.shape
-    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, NB - 1)
-    k = k_pool[bt].reshape(B, -1, Hkv, hd)      # (B, max_blocks*Bs, ...)
-    v = v_pool[bt].reshape(B, -1, Hkv, hd)
+    k = gather_pool(k_pool, block_tables)
+    v = gather_pool(v_pool, block_tables)
     return decode_ref(q, k, v, lengths, scale=scale)
+
+
+def gather_pool(pool, block_tables):
+    """Each sequence's pool blocks in logical order as a contiguous
+    (B, max_blocks * Bs, Hkv, hd) cache; pool (num_blocks, Hkv, Bs, hd).
+    Garbage table entries are clamped into the pool."""
+    NB, Hkv, Bs, hd = pool.shape
+    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, NB - 1)
+    blocks = jnp.swapaxes(pool[bt], 2, 3)       # (B, max_blocks, Bs, Hkv, hd)
+    return blocks.reshape(bt.shape[0], -1, Hkv, hd)
 
 
 def paged_prefill_ref(q, k_pool, v_pool, block_tables, lengths, q_offset,
@@ -98,16 +105,15 @@ def paged_prefill_ref(q, k_pool, v_pool, block_tables, lengths, q_offset,
     key positions ``<= q_offset + i``, additionally clamped to the
     ``lengths`` window so bucket-padded tail queries read no stale rows.
 
-    q (B,Sq,Hq,hd); k_pool/v_pool (num_blocks, Bs, Hkv, hd);
+    q (B,Sq,Hq,hd); k_pool/v_pool (num_blocks, Hkv, Bs, hd);
     block_tables (B, max_blocks) i32; lengths (B,) i32 visible window
     (= q_offset + true chunk length); q_offset (B,) i32 chunk start."""
     B, Sq, Hq, hd = q.shape
-    NB, Bs, Hkv, _ = k_pool.shape
+    Hkv = k_pool.shape[1]
     group = Hq // Hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
-    bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, NB - 1)
-    k = k_pool[bt].reshape(B, -1, Hkv, hd)      # logical order gather
-    v = v_pool[bt].reshape(B, -1, Hkv, hd)
+    k = gather_pool(k_pool, block_tables)       # logical order gather
+    v = gather_pool(v_pool, block_tables)
     S = k.shape[1]
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))

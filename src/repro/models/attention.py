@@ -47,7 +47,7 @@ def attn_fwd(
     positions,               # (B, S) or (B, S, 3) for mrope
     cfg: ModelConfig,
     cache: Optional[dict] = None,   # {"k","v"}: (B, S_max, Hkv, hd), or
-                                    # paged pools (NB, Bs, Hkv, hd)
+                                    # paged pools (NB, Hkv, Bs, hd)
     cache_len=None,          # i32 scalar: valid entries in cache
     mode: str = "train",     # train | prefill | decode
     block_tables=None,       # (B, max_blocks) i32: decode against paged
@@ -85,7 +85,7 @@ def attn_fwd(
             # lens[b] % Bs. The scheduler guarantees that block is
             # allocated and exclusively owned (COW resolved); idle slots
             # write out-of-bounds and are dropped.
-            NB, Bs = cache["k"].shape[0], cache["k"].shape[1]
+            NB, Bs = cache["k"].shape[0], cache["k"].shape[2]
             bt = jnp.asarray(block_tables, jnp.int32)
             pos = jnp.maximum(lens, 0)
             phys = jnp.take_along_axis(
@@ -93,10 +93,10 @@ def attn_fwd(
             )[:, 0]
             phys = jnp.where(lens >= 0, phys, NB)   # OOB => dropped
             off = pos % Bs
-            ck = cache["k"].at[phys, off].set(
+            ck = cache["k"].at[phys, :, off].set(
                 k[:, 0].astype(cache["k"].dtype), mode="drop"
             )
-            cv = cache["v"].at[phys, off].set(
+            cv = cache["v"].at[phys, :, off].set(
                 v[:, 0].astype(cache["v"].dtype), mode="drop"
             )
             o = ops.attention(
@@ -130,16 +130,16 @@ def attn_fwd(
         # the whole window with causal-by-absolute-position masking
         # (kernels paged_prefill / ref.paged_prefill_ref).
         assert cache is not None
-        NB, Bs = cache["k"].shape[0], cache["k"].shape[1]
+        Bs = cache["k"].shape[2]
         bt = jnp.asarray(block_tables, jnp.int32)
         start = jnp.broadcast_to(jnp.asarray(cache_len, jnp.int32), (B,))
         pos = start[:, None] + jnp.arange(S, dtype=jnp.int32)[None, :]
         phys = jnp.take_along_axis(bt, pos // Bs, axis=1)        # (B, S)
         off = pos % Bs
-        ck = cache["k"].at[phys, off].set(
+        ck = cache["k"].at[phys, :, off].set(
             k.astype(cache["k"].dtype), mode="drop"
         )
-        cv = cache["v"].at[phys, off].set(
+        cv = cache["v"].at[phys, :, off].set(
             v.astype(cache["v"].dtype), mode="drop"
         )
         o = ops.attention(

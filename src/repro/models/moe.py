@@ -52,13 +52,12 @@ def moe_fwd(p, x, cfg: ModelConfig, expert_perm=None) -> Tuple[jax.Array, dict]:
     fe_local·me_local), standard in EP frameworks."""
     mesh = None
     if cfg.moe_impl in ("auto", "ep"):
-        try:
-            m = jax.sharding.get_abstract_mesh()
-            if (m is not None and "model" in m.shape
-                    and cfg.n_experts % m.shape["model"] == 0):
-                mesh = m
-        except Exception:  # noqa: BLE001
-            mesh = None
+        from repro.dist.sharding import ambient_mesh
+
+        m = ambient_mesh()
+        if (m is not None and "model" in m.shape
+                and cfg.n_experts % m.shape["model"] == 0):
+            mesh = m
         if cfg.moe_impl == "ep" and mesh is None:
             raise ValueError("moe_impl='ep' needs an ambient mesh with a "
                              "'model' axis dividing n_experts")

@@ -109,12 +109,14 @@ def make_paged_cache(cfg: ModelConfig, num_blocks: int, block_size: int,
     """Paged serving cache: attention k/v are flat per-layer *pools* of
     ``num_blocks`` blocks of ``block_size`` tokens, shared by every
     sequence and indirected through per-sequence block tables
-    (serve/kvpool.py owns the mapping). Recurrent conv/ssm state is O(1)
-    per sequence, so it stays dense per decode slot."""
+    (serve/kvpool.py owns the mapping). Each block is head-major,
+    (n_kv_heads, block_size, hd), the layout the paged kernels read.
+    Recurrent conv/ssm state is O(1) per sequence, so it stays dense per
+    decode slot."""
     cache: Dict[str, Any] = {}
     na = n_attn_caches(cfg)
     if na:
-        kv = (na, num_blocks, block_size, cfg.n_kv_heads, cfg.hd)
+        kv = (na, num_blocks, cfg.n_kv_heads, block_size, cfg.hd)
         cache["k"] = jnp.zeros(kv, dtype)
         cache["v"] = jnp.zeros(kv, dtype)
     if cfg.family in ("ssm", "hybrid"):

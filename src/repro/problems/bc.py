@@ -15,7 +15,9 @@ matvec against the replicated adjacency), yielding between sweeps. The
 in-progress vertex is reported via ``work_in_state`` so GLB's hunger and
 termination logic accounts for it.
 
-Frontier sweeps are dense matvecs so the hot loop maps onto the MXU.
+Frontier sweeps are dense matvecs so the hot loop maps onto the MXU. They
+run at HIGHEST precision: path counts sigma outgrow bfloat16's 8-bit
+mantissa, and a TPU computes an f32 matmul in one bfloat16 pass by default.
 """
 from __future__ import annotations
 
@@ -25,6 +27,8 @@ import jax.numpy as jnp
 
 from repro.core.problem import GLBProblem
 from repro.core import taskbag as tb
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 ITEM_SPEC = {
     "lo": jax.ShapeDtypeStruct((), jnp.int32),
@@ -83,7 +87,8 @@ def bc_problem(adj: np.ndarray, capacity: int = 512, static_init: bool = True):
 
         def forward_sweep(st):
             frontier = (st["dist"] == st["level"]).astype(jnp.float32)
-            reach = (st["sigma"] * frontier) @ A        # contributions to dst
+            # contributions to dst
+            reach = jnp.dot(st["sigma"] * frontier, A, precision=HIGHEST)
             new = (st["dist"] < 0) & (reach > 0)
             dist = jnp.where(new, st["level"] + 1, st["dist"])
             sigma = st["sigma"] + reach * new
@@ -102,7 +107,7 @@ def bc_problem(adj: np.ndarray, capacity: int = 512, static_init: bool = True):
             coef = jnp.where(
                 at_l > 0, (1.0 + st["delta"]) / jnp.maximum(st["sigma"], 1e-30), 0.0
             )
-            contrib = A @ coef                          # sum over successors
+            contrib = jnp.dot(A, coef, precision=HIGHEST)  # sum over succ.
             at_prev = (st["dist"] == st["level"] - 1).astype(jnp.float32)
             delta = st["delta"] + st["sigma"] * contrib * at_prev
             lvl = st["level"] - 1

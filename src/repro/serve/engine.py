@@ -118,7 +118,7 @@ class Migration:
     tokens: List[int]          # cache contents = prompt bucket + out[:-1]
     written: int               # == len(tokens), the cache fill level
     block_size: int
-    kv: Optional[dict]         # {"k","v"}: np (layers, n, bs, heads, hd)
+    kv: Optional[dict]         # {"k","v"}: np (layers, n, heads, bs, hd)
 
 
 # Module-level jits (NOT per-engine closures): every engine with the same
@@ -237,12 +237,12 @@ def _make_paged_fns(cfg: ModelConfig, max_seq: int, block_size: int,
 
         def put(name, c, r):
             if name in ("k", "v"):
-                na = c.shape[0]
-                rb = r[:, 0].reshape(
-                    na, max_blocks, block_size, c.shape[-2], c.shape[-1]
+                # (layers, max_seq, heads, hd) row -> head-major blocks
+                na, heads, hd = c.shape[0], c.shape[2], c.shape[-1]
+                rb = r[:, 0].reshape(na, max_blocks, block_size, heads, hd)
+                return c.at[:, bt_scatter].set(
+                    jnp.swapaxes(rb, 2, 3).astype(c.dtype), mode="drop"
                 )
-                return c.at[:, bt_scatter].set(rb.astype(c.dtype),
-                                               mode="drop")
             start = (0, slot) + (0,) * (c.ndim - 2)
             return jax.lax.dynamic_update_slice(c, r.astype(c.dtype), start)
 

@@ -20,8 +20,8 @@ NUM_BLOCKS = 40  # physical pool blocks
 def _pool(B, Hq, Hkv, D, dtype=jnp.float32, salt=0):
     ks = jax.random.split(jax.random.fold_in(KEY, salt), 3)
     q = jax.random.normal(ks[0], (B, 1, Hq, D), dtype)
-    kp = jax.random.normal(ks[1], (NUM_BLOCKS, BS, Hkv, D), dtype)
-    vp = jax.random.normal(ks[2], (NUM_BLOCKS, BS, Hkv, D), dtype)
+    kp = jax.random.normal(ks[1], (NUM_BLOCKS, Hkv, BS, D), dtype)
+    vp = jax.random.normal(ks[2], (NUM_BLOCKS, Hkv, BS, D), dtype)
     return q, kp, vp
 
 
@@ -37,8 +37,11 @@ def _tables(lens, salt=0):
 
 
 def _gathered(kp, bt, b, L):
+    """Sequence b's first L tokens in logical order, (L, Hkv, D), from a
+    head-major (num_blocks, Hkv, BS, D) pool."""
     nblk = -(-L // BS)
-    return np.asarray(kp)[bt[b, :nblk]].reshape(-1, *kp.shape[2:])[:L]
+    blocks = np.asarray(kp)[bt[b, :nblk]].swapaxes(1, 2)   # (n, BS, Hkv, D)
+    return blocks.reshape(-1, kp.shape[1], kp.shape[3])[:L]
 
 
 @pytest.mark.parametrize("Hq,Hkv", [(4, 4), (8, 2)])  # MHA, GQA
@@ -135,8 +138,8 @@ def test_ops_attention_routes_paged_impls():
 def _prefill_case(B, Sq, Hq, Hkv, D, offs, true_lens, salt):
     ks = jax.random.split(jax.random.fold_in(KEY, salt), 3)
     q = jax.random.normal(ks[0], (B, Sq, Hq, D), jnp.float32)
-    kp = jax.random.normal(ks[1], (NUM_BLOCKS, BS, Hkv, D), jnp.float32)
-    vp = jax.random.normal(ks[2], (NUM_BLOCKS, BS, Hkv, D), jnp.float32)
+    kp = jax.random.normal(ks[1], (NUM_BLOCKS, Hkv, BS, D), jnp.float32)
+    vp = jax.random.normal(ks[2], (NUM_BLOCKS, Hkv, BS, D), jnp.float32)
     lens = [o + t for o, t in zip(offs, true_lens)]
     bt = _tables(lens, salt=salt)
     return q, kp, vp, bt, np.asarray(lens, np.int32), np.asarray(
