@@ -6,7 +6,9 @@ continuous-batching scheduler, radix cache, and replica balancer:
 
   trace.py    — Chrome trace_event spans/instants/counters (Perfetto),
                 request-lifecycle async spans keyed by request id,
-                NullTracer disabled default (one attribute check).
+                NullTracer disabled default (one attribute check),
+                ProfilerTracer: the same duration spans as
+                jax.profiler annotations (``repro.<span>``).
   metrics.py  — counters / gauges / fixed-bucket histograms with
                 snapshot()/merged() compatible with
                 core.stats.merge_place_stats, Prometheus rendering.
@@ -19,8 +21,9 @@ continuous-batching scheduler, radix cache, and replica balancer:
   slo.py      — SLOMonitor: declared TTFT/TPOT/queue-wait targets,
                 rolling windows, multi-window burn-rate alerts.
 """
-from .trace import (NULL_TRACER, NullTracer, Tracer, atomic_write_json,
-                    clock_sync, now_us, validate_chrome_trace)
+from .trace import (NULL_TRACER, NullTracer, ProfilerTracer, Tracer,
+                    atomic_write_json, clock_sync, now_us,
+                    validate_chrome_trace)
 from .metrics import (DEFAULT_BYTE_BUCKETS, DEFAULT_MS_BUCKETS, Counter,
                       Gauge, Histogram, MetricsRegistry,
                       quantiles_from_values)
@@ -43,6 +46,7 @@ def __getattr__(name):
 __all__ = [
     "NULL_TRACER",
     "NullTracer",
+    "ProfilerTracer",
     "Tracer",
     "atomic_write_json",
     "clock_sync",

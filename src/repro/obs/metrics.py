@@ -31,6 +31,15 @@ DEFAULT_MS_BUCKETS = (
     0.05, 0.1, 0.2, 0.5, 1.0, 2.0, 5.0, 10.0, 20.0, 50.0, 100.0, 200.0,
     500.0, 1000.0, 2000.0, 5000.0, 10000.0, 30000.0,
 )
+# Queue-wait buckets (ms): geometric from 1 ms to 60 s, each at most 10%
+# wider than the last, so a quantile read from them is within 10% of the
+# sample's. The default buckets double or more per step: a p95 read from
+# them is a guess anywhere inside a 2x bucket.
+QUEUE_WAIT_MS_BUCKETS = tuple(60_000.0 ** (k / 116) for k in range(117))
+# Histograms whose buckets are not the default: the registry creates them
+# with these wherever they are first asked for, so every replica's
+# histogram has the same layout and merges.
+NAMED_BUCKETS = {"queue_wait_ms": QUEUE_WAIT_MS_BUCKETS}
 # Byte-size buckets (KiB-scale) for migration payloads.
 DEFAULT_BYTE_BUCKETS = tuple(float(4 ** k * 256) for k in range(12))
 
@@ -168,10 +177,14 @@ class MetricsRegistry:
         return self._gauges[name]
 
     def histogram(self, name: str,
-                  bounds: Sequence[float] = DEFAULT_MS_BUCKETS
-                  ) -> Histogram:
+                  bounds: Optional[Sequence[float]] = None) -> Histogram:
+        """The histogram ``name``, made on first use with ``bounds``, or
+        else the name's own layout (:data:`NAMED_BUCKETS`), or else
+        :data:`DEFAULT_MS_BUCKETS`."""
         if name not in self._hists:
             self._check_unique(name, self._hists)
+            if bounds is None:
+                bounds = NAMED_BUCKETS.get(name, DEFAULT_MS_BUCKETS)
             self._hists[name] = Histogram(bounds)
         return self._hists[name]
 

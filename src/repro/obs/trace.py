@@ -1,5 +1,4 @@
-"""Fabric-wide tracing: monotonic-clock spans + instant events exported
-as Chrome ``trace_event`` JSON (load the file at https://ui.perfetto.dev).
+"""Fabric-wide tracing: one span vocabulary, two sinks.
 
 The GLB paper makes per-worker *logging* a first-class library feature
 (§2.4: time processing vs distributing, steals sent/received, workload
@@ -7,10 +6,13 @@ shipped); this module is the timeline-resolved generalization for the
 whole stack — one trace vocabulary shared by taskbag GLB runs and the
 LM serving fabric:
 
-* **duration spans** (``ph: B/E``) — per-replica work: engine steps,
-  prefill chunks, migration pack/land, GLB supersteps. Owned by the
-  ``(pid, tid)`` track that opened them; ``Tracer`` keeps a per-track
-  stack so ``end()`` needs no name and export can prove balance.
+* **duration spans** (``ph: B/E``) — per-replica work: engine steps and
+  their phases (``plan``, ``prefill``, ``decode``, ``drain``), prefill
+  chunks, migration pack/land, the balancer's ``superstep``,
+  ``balance`` and ``match_steals``, GLB sim supersteps. Owned by the
+  ``(pid, tid)`` track that opened them; each sink keeps a per-track
+  stack so ``end()`` needs no name, and ``span()`` closes whatever its
+  body left open on its track, so an exception cannot leave a span open.
 * **request lifecycle spans** (async ``ph: b/n/e``, keyed by request
   id) — ``queued -> prefill -> decode -> finished`` with ``preempted`` /
   ``resumed`` / ``migrated_out`` / ``migrated_in`` instants in between.
@@ -23,17 +25,28 @@ LM serving fabric:
   budget split, fabric load vector: the measurement substrate for
   cost-modeled balancing.
 
+The two sinks:
+
+* :class:`Tracer` collects everything above in memory and writes
+  Chrome trace_event JSON (load it at https://ui.perfetto.dev). Its
+  clock is ``time.perf_counter_ns() / 1e3`` µs; :func:`clock_sync`
+  returns a ``(unix_ts, perf_us)`` pair, stamped into the export's
+  ``otherData`` and into every ``BENCH_*.json`` by ``benchmarks/run.py``,
+  so bench rows and trace events share one axis.
+* :class:`ProfilerTracer` turns the duration spans into
+  ``jax.profiler.TraceAnnotation`` events named ``repro.<span>``: inside
+  a ``jax.profiler`` capture they land in the same ``.xplane.pb`` as the
+  device's ops, on the profiler's clock, so a gap in the device's work
+  can be put down to the program phase that held the host. Instants,
+  counters and request spans are no-ops there.
+
 Overhead contract: the default is the module-level :data:`NULL_TRACER`
 whose ``enabled`` is False — every instrumentation site guards with
 ``if tracer.enabled:``, so the disabled hot path pays ONE attribute
 check and no call, no allocation, no clock read. ``bench_serve``
-measures tracer-on vs tracer-off tokens/s and CI warns past 5%.
-
-Clock domain: timestamps are ``time.perf_counter_ns() / 1e3`` µs.
-:func:`clock_sync` returns a ``(unix_ts, perf_us)`` pair; the tracer
-stamps one into the export's ``otherData`` and ``benchmarks/run.py``
-stamps one into every ``BENCH_*.json``, so bench rows and trace events
-can be correlated on one axis.
+measures the Chrome tracer's tokens/s cost on the CPU (advisory in
+``benchmarks/compare.py``; its host-sync count gates hard); the
+profiler sink's cost is measured on the chip (PERF.md).
 """
 from __future__ import annotations
 
@@ -177,11 +190,17 @@ class Tracer:
     @contextmanager
     def span(self, name: str, pid: int = 0, tid: int = 0,
              cat: Optional[str] = None, args: Optional[dict] = None):
+        """``begin`` .. ``end`` around the body. On exit it also closes
+        any span the body opened on this track and left open (a phase
+        raised through between its ``begin`` and ``end``), so the
+        track's stack is back where it was."""
+        depth = len(self._stacks.get((pid, tid), ()))
         self.begin(name, pid=pid, tid=tid, cat=cat, args=args)
         try:
             yield
         finally:
-            self.end(pid=pid, tid=tid)
+            while len(self._stacks.get((pid, tid), ())) > depth:
+                self.end(pid=pid, tid=tid)
 
     # ------------------------------------------------------ instants/counters
     def instant(self, name: str, pid: int = 0, tid: int = 0,
@@ -318,6 +337,39 @@ class Tracer:
     def write(self, path: str) -> None:
         self.flush()
         atomic_write_json(path, self.to_chrome())
+
+
+class ProfilerTracer(NullTracer):
+    """Duration spans written as ``jax.profiler.TraceAnnotation`` events
+    named ``repro.<span>``, so that a ``jax.profiler`` capture holds the
+    program's phases beside the device's ops, on one clock. Outside a
+    capture an annotation records nothing. Everything else (instants,
+    counters, request spans, export) is the :class:`NullTracer`'s no-op.
+    Spans are LIFO per ``(pid, tid)`` track, as in :class:`Tracer`;
+    annotations nest by time on the calling thread, so spans of
+    different tracks must nest in time too (the engine's per-slot
+    prefill spans sit inside its ``engine_step``)."""
+
+    enabled = True
+    PREFIX = "repro."
+
+    def __init__(self):
+        import jax              # the profiler sink is the one user of jax
+
+        self._annotation = jax.profiler.TraceAnnotation
+        self._stacks: Dict[tuple, list] = {}
+
+    def begin(self, name: str, pid: int = 0, tid: int = 0, *a, **k) -> None:
+        ann = self._annotation(self.PREFIX + name)
+        ann.__enter__()
+        self._stacks.setdefault((pid, tid), []).append(ann)
+
+    def end(self, pid: int = 0, tid: int = 0, *a, **k) -> None:
+        stack = self._stacks.get((pid, tid))
+        if stack:           # unmatched end: drop, as Tracer does
+            stack.pop().__exit__(None, None, None)
+
+    span = Tracer.span
 
 
 def validate_chrome_trace(trace: dict) -> List[str]:

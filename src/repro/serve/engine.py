@@ -358,6 +358,9 @@ class Engine:
         self.migrations_seeded = 0  # landed via a planted radix prefix
         self.migrations_recompute = 0   # landed WITHOUT KV (recompute)
         self._seed_sid = -1         # temp seq ids for radix seeding
+        # Fresh first tokens synced this step: the step's end observes
+        # how long each was held (first_token_hold_ms).
+        self._first_tokens: List[Request] = []
         if paged:
             bs = block_size or paged_block_kv(max_seq, cfg.hd)
             assert max_seq % bs == 0, (max_seq, bs)
@@ -529,6 +532,7 @@ class Engine:
                 self.tokens[i, 0] = first
                 self.tokens_out += 1
                 req.t_first = now_us()
+                self._first_tokens.append(req)
                 self.metrics.histogram("prefill_chunk_ms").observe(
                     (req.t_first - t_adm) / 1e3
                 )
@@ -645,6 +649,7 @@ class Engine:
             self.budget[slot] = req.max_new
             self.tokens_out += 1
             req.t_first = now_us()
+            self._first_tokens.append(req)
             if req.t_submit:
                 ttft_ms = (req.t_first - req.t_submit) / 1e3
                 self.metrics.histogram("ttft_ms").observe(ttft_ms)
@@ -933,9 +938,41 @@ class Engine:
             bt[i, : len(t)] = t
         return jnp.asarray(bt)
 
+    def _decode_and_drain(self, lens: np.ndarray, budget: np.ndarray):
+        """The step's decode burst: block tables and inputs uploaded,
+        one ``_decode_n`` dispatch (the ``decode`` span), then the single
+        drain (the ``drain`` span). A call made while requests wait in
+        the queue counts toward ``decode_calls_backlogged``, and its
+        active slots toward ``decode_slots_backlogged``: how full the
+        batch is when work is waiting."""
+        if self.queue:
+            self.metrics.counter("decode_calls_backlogged").inc()
+            self.metrics.counter("decode_slots_backlogged").inc(
+                int((lens >= 0).sum()))
+        traced = self.tracer.enabled
+        if traced:
+            self.tracer.begin("decode", pid=self.replica_id)
+        bt = self._device_tables() if self.paged else None
+        buf, self.cache, self._key = self._decode_n(
+            self.params, jnp.asarray(self.tokens), self.cache, bt,
+            jnp.asarray(lens), jnp.asarray(budget), self._key,
+        )
+        if traced:
+            self.tracer.end(pid=self.replica_id)
+            self.tracer.begin("drain", pid=self.replica_id)
+        buf = np.asarray(buf)               # the single drain
+        self.host_syncs += 1
+        self._drain(buf)
+        if traced:
+            self.tracer.end(pid=self.replica_id)
+
     def _step_paged(self):
+        if self.tracer.enabled:
+            self.tracer.begin("plan", pid=self.replica_id)
         plan = self.sched.plan_step(self.queue, self.slots, self.lens,
                                     self._prefix_tokens)
+        if self.tracer.enabled:
+            self.tracer.end(pid=self.replica_id)
         for slot, _req in plan.preempted:
             self.lens[slot] = -1
             self.budget[slot] = 0
@@ -970,14 +1007,7 @@ class Engine:
                 np.minimum(np.minimum(self.budget, cap_left), plan.quota),
                 self.budget,
             ).astype(np.int32)
-            buf, self.cache, self._key = self._decode_n(
-                self.params, jnp.asarray(self.tokens), self.cache,
-                self._device_tables(), jnp.asarray(step_lens),
-                jnp.asarray(step_budget), self._key,
-            )
-            buf = np.asarray(buf)           # the single drain
-            self.host_syncs += 1
-            self._drain(buf)
+            self._decode_and_drain(step_lens, step_budget)
         self.steps += 1
 
     # ------------------------------------------------------------------ step
@@ -988,27 +1018,39 @@ class Engine:
         admission/preemption to the continuous-batching scheduler.
         Traced runs wrap the iteration in an ``engine_step`` span and
         emit load/pool counter tracks; the untraced path dispatches
-        straight to the implementation (one attribute check)."""
+        straight to the implementation (one attribute check). The step's
+        end is when the front end can see its tokens: each fresh first
+        token's hold, from its sync to here, goes to the always-on
+        ``first_token_hold_ms`` histogram."""
         if not self.tracer.enabled:
-            return self._step_impl()
-        with self.tracer.span("engine_step", pid=self.replica_id,
-                              args={"step": self.steps}):
             self._step_impl()
-            self.tracer.counter(
-                "load",
-                {"running": float(sum(s is not None for s in self.slots)),
-                 "queued": float(len(self.queue))},
-                pid=self.replica_id,
-            )
-            if self.paged:
-                ps = self.pool.stats()
+        else:
+            with self.tracer.span("engine_step", pid=self.replica_id,
+                                  args={"step": self.steps}):
+                self._step_impl()
                 self.tracer.counter(
-                    "pool",
-                    {"occupancy_pct": round(100 * ps.occupancy, 2),
-                     "available_blocks": float(self.pool.available_blocks),
-                     "watermark": float(self.sched.watermark)},
+                    "load",
+                    {"running": float(sum(s is not None
+                                          for s in self.slots)),
+                     "queued": float(len(self.queue))},
                     pid=self.replica_id,
                 )
+                if self.paged:
+                    ps = self.pool.stats()
+                    self.tracer.counter(
+                        "pool",
+                        {"occupancy_pct": round(100 * ps.occupancy, 2),
+                         "available_blocks":
+                             float(self.pool.available_blocks),
+                         "watermark": float(self.sched.watermark)},
+                        pid=self.replica_id,
+                    )
+        if self._first_tokens:
+            t_end = now_us()
+            hold = self.metrics.histogram("first_token_hold_ms")
+            for req in self._first_tokens:
+                hold.observe((t_end - req.t_first) / 1e3)
+            self._first_tokens.clear()
 
     def _step_impl(self):
         if self.paged:
@@ -1019,13 +1061,7 @@ class Engine:
         self.peak_running = max(
             self.peak_running, sum(s is not None for s in self.slots)
         )
-        buf, self.cache, self._key = self._decode_n(
-            self.params, jnp.asarray(self.tokens), self.cache, None,
-            jnp.asarray(self.lens), jnp.asarray(self.budget), self._key,
-        )
-        buf = np.asarray(buf)               # the single drain
-        self.host_syncs += 1
-        self._drain(buf)
+        self._decode_and_drain(self.lens, self.budget)
         self.steps += 1
 
     def step_legacy(self):
@@ -1590,7 +1626,14 @@ class GLBReplicaBalancer:
         the load vector gathered for the steal matching doubles as the
         GLB termination detector, so callers need no separate poll (and,
         with a fault injector attached, the same gather is the
-        heartbeat: see ``_detect_failures``)."""
+        heartbeat: see ``_detect_failures``). Traced runs wrap the pass
+        in a ``balance`` span on the fabric track."""
+        if not self.tracer.enabled:
+            return self._balance()
+        with self.tracer.span("balance", pid=self._fabric_pid):
+            return self._balance()
+
+    def _balance(self) -> bool:
         if self.faults is not None:
             self.faults.begin_superstep(self.supersteps)
         self._detect_failures()
@@ -1638,6 +1681,8 @@ class GLBReplicaBalancer:
             [active[i] and e.can_accept() and len(e.queue) == 0
              for i, e in enumerate(self.engines)]
         )
+        if self.tracer.enabled:
+            self.tracer.begin("match_steals", pid=self._fabric_pid)
         m = match_steals(
             jnp.asarray(sizes), jnp.asarray(hungry), self._pending,
             jax.random.fold_in(jax.random.key(17), self._step),
@@ -1645,6 +1690,8 @@ class GLBReplicaBalancer:
         )
         self._pending = m.pending
         src = np.asarray(m.src)
+        if self.tracer.enabled:
+            self.tracer.end(pid=self._fabric_pid)
         for thief, victim in enumerate(src):
             if victim < 0:
                 continue

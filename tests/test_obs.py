@@ -15,6 +15,7 @@ from repro.models import init_lm
 from repro.obs import (DEFAULT_MS_BUCKETS, NULL_TRACER, Histogram,
                        MetricsRegistry, Tracer, clock_sync,
                        quantiles_from_values, validate_chrome_trace)
+from repro.obs.metrics import QUEUE_WAIT_MS_BUCKETS
 from repro.problems.uts import uts_problem
 from repro.serve.engine import Engine, GLBReplicaBalancer, Request
 
@@ -326,6 +327,72 @@ def test_nulltracer_and_tracer_token_identity():
 
     assert Engine(CFG, PARAMS, **KW).tracer is NULL_TRACER
     assert outs(None) == outs(Tracer())
+
+
+def test_queue_wait_p95_within_ten_percent_across_merged_replicas():
+    """queue_wait_ms has its own geometric buckets (no wider than 10%,
+    1 ms .. 60 s), made wherever the histogram is first asked for, so
+    replica registries merge and the merged p95 is within 10% of the
+    sample's."""
+    b = QUEUE_WAIT_MS_BUCKETS
+    assert b[0] == 1.0 and b[-1] >= 60_000.0
+    assert max(hi / lo for lo, hi in zip(b, b[1:])) <= 1.1
+    rng = np.random.default_rng(11)
+    regs, draws = [MetricsRegistry() for _ in range(3)], []
+    for i, reg in enumerate(regs):
+        v = np.exp(rng.normal(np.log(300.0) + i, 1.0, size=700))   # ms
+        for x in v:
+            reg.histogram("queue_wait_ms").observe(x)
+        draws.append(v)
+    merged = MetricsRegistry.merged(regs).histogram("queue_wait_ms")
+    assert merged.bounds == b
+    true = float(np.percentile(np.concatenate(draws), 95))
+    assert abs(merged.quantile(0.95) - true) <= 0.1 * true, (
+        merged.quantile(0.95), true)
+    # Other histograms keep the default layout.
+    assert MetricsRegistry().histogram("ttft_ms").bounds == \
+        DEFAULT_MS_BUCKETS
+
+
+def test_first_token_hold_once_per_fresh_admission_never_on_resume():
+    """Forced preemption: each request is admitted fresh once and
+    resumed at least once in all; only the fresh admissions observe a
+    first-token hold."""
+    kw = dict(max_slots=2, max_seq=32, pad_len=8, steps_per_sync=8)
+    reqs = [Request(rid=i, prompt=[3, i + 1, 4, 2], max_new=14 + i % 4)
+            for i in range(5)]
+    e = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=5, **kw)
+    _drive(e, reqs)
+    assert e.sched.preemptions > 0, "pool sizing must force preemption"
+    hold = e.metrics.histogram("first_token_hold_ms")
+    assert hold.count == len(reqs) == \
+        e.sched.admissions - e.sched.preemptions
+    assert hold.vmin >= 0.0
+    # The contiguous engine's admissions observe it the same way.
+    c = Engine(CFG, PARAMS, **kw)
+    _drive(c, [Request(rid=i, prompt=[3, i + 1], max_new=5)
+               for i in range(3)])
+    assert c.metrics.histogram("first_token_hold_ms").count == 3
+
+
+@pytest.mark.parametrize("paged", [True, False])
+def test_backlogged_decode_counters_count_only_calls_with_a_queue(paged):
+    e = Engine(CFG, PARAMS, paged=paged, **KW)
+    seen = []
+    decode = e._decode_n
+
+    def spy(params, tokens, cache, bt, lens, budget, key):
+        seen.append((len(e.queue), int((np.asarray(lens) >= 0).sum())))
+        return decode(params, tokens, cache, bt, lens, budget, key)
+
+    e._decode_n = spy
+    _drive(e, [Request(rid=i, prompt=[3, i + 1, 4], max_new=6 + i)
+               for i in range(5)])
+    backlogged = [active for queued, active in seen if queued]
+    assert backlogged and len(backlogged) < len(seen)
+    snap = e.stats()
+    assert snap["decode_calls_backlogged"] == len(backlogged)
+    assert snap["decode_slots_backlogged"] == sum(backlogged)
 
 
 def test_stats_is_registry_view_and_merge_superset():
