@@ -41,19 +41,19 @@ def decode_block_k(kv_len: int, head_dim: int) -> int:
     return _block_from_table(DECODE_BLOCK_K, kv_len, head_dim)
 
 
-# Paged KV pool block sizes (tokens per block), keyed by head_dim. The
-# paged kernel streams exactly one pool block per grid step, so this is
-# both the allocator granularity and the kernel tile: small enough that
-# internal fragmentation (the partially-filled tail block per sequence)
-# stays low at production request lengths, large enough that the (group, D) x
-# (Bs, D)^T step keeps the MXU lanes busy and the per-block DMA amortizes.
-# 4-8x smaller than DECODE_BLOCK_K — the contiguous kernel pays
-# fragmentation at *bucket* granularity instead, so it wants big tiles.
+# Paged KV pool block sizes (tokens per block), keyed by head_dim: the
+# allocator granularity. Small enough that internal fragmentation (the
+# partially-filled tail block per sequence) stays low at production
+# request lengths; at 128-lane head dims the paged decode kernel copies
+# many blocks per grid step (kernels.paged_decode.paged_decode_grid), so
+# its tile does not shrink with them. 4-8x smaller than DECODE_BLOCK_K —
+# the contiguous kernel pays fragmentation at *bucket* granularity
+# instead.
 PAGED_BLOCK_KV = {32: 64, 64: 64, 128: 32, 256: 16}
 
 
 def paged_block_kv(max_seq: int, head_dim: int) -> int:
-    """Pool/kernel block size for kernels.paged_decode: table lookup by
+    """Pool block size for the paged engine and kernels: table lookup by
     head_dim, halved until it divides the per-sequence cache cap (the
     block-table width max_seq // block must be exact)."""
     return _block_from_table(PAGED_BLOCK_KV, max_seq, head_dim)

@@ -1,44 +1,56 @@
-"""Paged split-KV Pallas flash-decode kernel: one query token against a
+"""Paged Pallas flash-decode kernel: one query token against a
 block-table-indirected KV pool (Sq == 1, the paged serving hot path) —
-plus ``paged_prefill``, the same block walk for a *chunk* of Sq query
+plus ``paged_prefill``, a one-block walk for a *chunk* of Sq query
 tokens at offset ``q_offset`` (chunked prefill: tokens ``[s, e)``
 attending causally to pool blocks ``[0, e)``).
 
 flash_decode.py streams a *contiguous* per-slot cache; here the cache is
-a flat pool of KV blocks shared by every sequence (serve/kvpool.py) and
-each sequence names its blocks through a block table. Two scalar-prefetch
-operands — ``block_tables`` (B, max_blocks) i32 and ``lengths`` (B,) i32
-— arrive before the kernel body runs, so the k/v **index maps walk the
-table**: grid step ``(b, h, j)`` fetches physical block
-``block_tables[b, min(j, last_live(b))]`` instead of row-range
-``[j*bk, (j+1)*bk)`` of a dense cache. The same three-level gating as
-the contiguous kernel applies:
+a flat pool of KV blocks ("pages") shared by every sequence
+(serve/kvpool.py) and each sequence names its pages through a block
+table. Two scalar-prefetch operands — ``block_tables`` (B, max_blocks)
+i32 and ``lengths`` (B,) i32 — arrive before the kernel body runs.
 
-  * DMA clamp   — past-window grid steps clamp the *logical* block index
-    to the last live one; the table lookup then repeats the same physical
-    block, the pipeline sees an unchanged index and issues no DMA — dead
-    blocks are never fetched;
-  * block skip  — ``pl.when`` drops compute for blocks at or past the
-    window (idle slots, window == 0, skip everything and emit zeros);
-  * lane mask   — the partial tail block masks key positions >= window.
+``paged_decode`` decouples the compute block from the pool block: grid
+step ``(b, j)`` of the grid ``(batch, max_blocks // P)`` handles pages
+``block_tables[b, j*P : (j+1)*P]`` of slot b for every kv head. The
+pools stay in HBM (``memory_space=pl.ANY``); the pool is head-major,
+(num_blocks, Hkv, Bs, D), so one async copy per page carries all kv
+heads into a double-buffered VMEM scratch (2, P, Hkv, Bs, D). Each live
+step starts the copies of the next live step (the same slot's next step,
+or step 0 of the next slot with a window) before waiting for its own, so
+copies overlap compute, across slots too. Gating:
 
-The pool is head-major, (num_blocks, Hkv, Bs, D), so one grid step reads
-a (Bs, D) block of one kv head — a shape the TPU compiler tiles for any
-Bs and D. The KV block size is the pool's block size (one pool block per
-grid step), chosen by ``core.autotune.paged_block_kv``. Decode runs the
-contiguous kernel's body (flash_decode.decode_kernel): each step takes the
-``group`` query heads of one kv head as a (group, D) block, so the grid
-is (batch, kv_heads, max_blocks). Oracle: ``ref.paged_decode_ref``
-(gather blocks -> decode_ref). Routed via
-``ops.attention(..., block_tables=...)``.
+  * dead steps — a step wholly past its slot's window copies and
+    computes nothing; the last live step copies only its live pages, so
+    table entries past the allocation are never read; idle slots
+    (window 0) emit exact zeros;
+  * lane mask  — keys at or past the window score -inf and their value
+    rows are zeroed, so stale buffer rows never reach the accumulator.
 
-``paged_prefill`` generalizes the walk to an (Sq, D) query block of one
-query head (grid (batch, q_heads, max_blocks), kv head h // group) and a
-third scalar-prefetch operand ``q_offset`` ((B,) i32 chunk start): the
-mask becomes causal-by-absolute-position (``kpos <= q_offset + i``)
-intersected with the ``lengths`` window; table walk, DMA clamp, block
-skip and lane mask are unchanged. Oracle: ``ref.paged_prefill_ref``;
-routed via ``ops.attention(..., block_tables=..., q_offset=...)``.
+Per kv head, the ``group`` query heads are the rows of the f32 online
+softmax (flash_attention.online_softmax_step) against the step's P*Bs
+keys. ``paged_decode_grid`` chooses P from the shapes alone: the largest
+divisor of max_blocks with at most 512 tokens a step whose
+double-buffered k/v pages fit a 4 MiB VMEM budget.
+
+A head dim that is not a multiple of 128 lanes (TinyLlama 64, Zamba2
+112) keeps the one-block walk: grid (batch, kv_heads, max_blocks), one
+(Bs, D) block of one kv head a step through the BlockSpec pipeline
+(``_walk``, flash_decode.decode_kernel). Mosaic slices a ref only in
+whole 128-lane tiles, so the page copies cannot address such a pool, and
+padding it to the lane width would move the whole pool every call.
+Oracle: ``ref.paged_decode_ref`` (gather blocks -> decode_ref). Routed
+via ``ops.attention(..., block_tables=...)``.
+
+``paged_prefill`` walks one pool block a grid step (``_walk``), grid
+(batch, q_heads, max_blocks), with an (Sq, D) query block of one query
+head (kv head h // group) and a third scalar-prefetch operand
+``q_offset`` ((B,) i32 chunk start). Past-window steps clamp the logical
+block to the last live one, so the physical index repeats and the
+pipeline issues no DMA; ``pl.when`` skips their compute; the mask is
+causal-by-absolute-position (``kpos <= q_offset + i``) intersected with
+the ``lengths`` window. Oracle: ``ref.paged_prefill_ref``; routed via
+``ops.attention(..., block_tables=..., q_offset=...)``.
 """
 from __future__ import annotations
 
@@ -63,14 +75,143 @@ def _walk(bt, lens, b, j, Bs, NB):
     return jnp.clip(bt[b, jnp.minimum(j, last)], 0, NB - 1)
 
 
+# Compute-step caps for paged_decode: at most this many kv tokens per
+# grid step, and at most this many bytes of double-buffered k/v pages.
+_STEP_TOKENS = 512
+_KV_VMEM_BYTES = 4 << 20
+_LANES = 128
+
+
+def paged_decode_grid(max_blocks, Bs, D, Hkv, dtype):
+    """How ``paged_decode`` walks one slot's block table: ``(pages,
+    steps)``, the pool blocks one grid step reads and the grid steps a
+    slot takes. A head dim that is a multiple of 128 lanes copies pages
+    of every kv head: pages is the largest divisor of ``max_blocks`` with
+    at most ``_STEP_TOKENS`` tokens whose double-buffered k and v pages
+    (2 x 2 x pages x Hkv x Bs x D) fit in ``_KV_VMEM_BYTES``, at least 1,
+    and steps is ``max_blocks // pages``. Any other head dim walks one
+    block of one kv head a step: ``(1, Hkv * max_blocks)``."""
+    if D % _LANES:
+        return 1, Hkv * max_blocks
+    page_bytes = Hkv * Bs * D * jnp.dtype(dtype).itemsize
+    best = 1
+    for p in range(2, max_blocks + 1):
+        if p * Bs > _STEP_TOKENS or 4 * p * page_bytes > _KV_VMEM_BYTES:
+            break
+        if max_blocks % p == 0:
+            best = p
+    return best, max_blocks // best
+
+
+def paged_decode_live_steps(windows, max_blocks, Bs, D, Hkv, dtype):
+    """Grid steps of ``paged_decode`` that read pool blocks, summed over
+    ``windows`` (an int array, one visible window per slot and call; 0 for
+    an idle slot). Steps past a window read nothing."""
+    pages, steps = paged_decode_grid(max_blocks, Bs, D, Hkv, dtype)
+    heads = steps // (max_blocks // pages)   # kv heads a table entry takes
+    w = np.asarray(windows, np.int64)
+    return int((-(-w // (pages * Bs))).sum()) * heads
+
+
+def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
+                         k_buf, v_buf, sems, state, acc_ref, m_ref, l_ref,
+                         *, scale, pages, num_steps):
+    """Grid step (b, j): pool pages ``[j*P, (j+1)*P)`` of slot b, every kv
+    head. ``state`` (SMEM) holds the k/v buffer the step's pages land in
+    and whether an earlier step already started their copies."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, j = pl.program_id(0), pl.program_id(1)
+    B = pl.num_programs(0)
+    NB, Hkv, Bs, _ = k_hbm.shape
+    bk = pages * Bs
+
+    def live_pages(b, j):
+        return jnp.clip(pl.cdiv(len_ref[b], Bs) - j * pages, 0, pages)
+
+    def page_copies(b, j, buf, start):
+        """Start (else wait for) the copies of step (b, j)'s live pages
+        into buffer ``buf``: one copy per page carries all Hkv heads."""
+        def body(i, carry):
+            page = jnp.clip(bt_ref[b, j * pages + i], 0, NB - 1)
+            for hbm, vmem, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
+                copy = pltpu.make_async_copy(
+                    hbm.at[page], vmem.at[buf, i], sems.at[s, buf]
+                )
+                copy.start() if start else copy.wait()
+            return carry
+
+        jax.lax.fori_loop(0, live_pages(b, j), body, 0)
+
+    @pl.when((b == 0) & (j == 0))
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    @pl.when(j == 0)
+    def _init():
+        init_scratch(acc_ref, m_ref, l_ref)
+
+    n = len_ref[b]
+
+    @pl.when(j * bk < n)  # steps wholly past the window copy nothing
+    def _body():
+        cur = state[0]
+
+        @pl.when(state[1] == 0)  # first live step: nothing prefetched
+        def _():
+            page_copies(b, j, cur, start=True)
+
+        # Prefetch the next live step, in this slot or the next slot with
+        # a window, while this step computes.
+        nb = jax.lax.while_loop(
+            lambda c: (c < B) & (len_ref[jnp.minimum(c, B - 1)] == 0),
+            lambda c: c + 1, b + 1,
+        )
+        same = (j + 1) * bk < n
+        nxt_b = jnp.where(same, b, nb)
+        has_next = nxt_b < B
+
+        @pl.when(has_next)
+        def _():
+            page_copies(nxt_b, jnp.where(same, j + 1, 0), 1 - cur,
+                        start=True)
+
+        state[0] = 1 - cur
+        state[1] = has_next.astype(jnp.int32)
+        page_copies(b, j, cur, start=False)
+
+        # Keys at or past the window: masked scores, and zeroed values, so
+        # stale buffer rows (pages not copied this step) never reach acc.
+        G = q_ref.shape[2]
+        key_live = j * bk + jax.lax.broadcasted_iota(
+            jnp.int32, (G, bk), 1) < n
+        row_live = j * bk + jax.lax.broadcasted_iota(
+            jnp.int32, (bk, 1), 0) < n
+        for h in range(Hkv):
+            k = k_buf[cur, :, h].astype(jnp.float32).reshape(bk, -1)
+            v = v_buf[cur, :, h].astype(jnp.float32).reshape(bk, -1)
+            online_softmax_step(
+                q_ref[0, h].astype(jnp.float32), k,
+                jnp.where(row_live, v, 0.0), key_live,
+                acc_ref.at[h], m_ref.at[h], l_ref.at[h], scale,
+            )
+
+    @pl.when(j == num_steps - 1)
+    def _finish():
+        for h in range(Hkv):
+            o_ref[0, h] = finish(acc_ref.at[h], l_ref.at[h]).astype(
+                o_ref.dtype)
+
+
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
                  scale: float | None = None, interpret: bool = False):
     """q: (B, 1, Hq, D); k_pool, v_pool: (num_blocks, Hkv, Bs, D) flat
     block pools; block_tables: (B, max_blocks) i32 physical block per
     logical block (entries past the allocation may be any value — they
-    are clamped away); lengths: (B,) i32 visible window (0 => idle slot,
-    output zeros). Returns (B, 1, Hq, D)."""
+    never reach the result); lengths: (B,) i32 visible window (0 => idle
+    slot, output zeros). Returns (B, 1, Hq, D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, Sq, Hq, D = q.shape
@@ -82,6 +223,58 @@ def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
     scale = float(scale) if scale is not None else 1.0 / np.sqrt(D)
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    q = q.reshape(B, Hkv, group, D)
+    if D % _LANES:
+        out = _paged_decode_walk(q, k_pool, v_pool, bt, lens, scale,
+                                 interpret)
+        return out.reshape(B, 1, Hq, D)
+    pages, num_steps = paged_decode_grid(max_blocks, Bs, D, Hkv,
+                                         k_pool.dtype)
+
+    slot_map = lambda b, j, bt, lens: (b, 0, 0, 0)
+    page_buf = pltpu.VMEM((2, pages, Hkv, Bs, D), k_pool.dtype)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(B, num_steps),
+        in_specs=[
+            pl.BlockSpec((1, Hkv, group, D), slot_map),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, Hkv, group, D), slot_map),
+        scratch_shapes=[
+            page_buf, page_buf,
+            pltpu.SemaphoreType.DMA((2, 2)),   # (k|v, buffer)
+            pltpu.SMEM((2,), jnp.int32),       # (buffer, prefetched)
+            pltpu.VMEM((Hkv, group, D), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
+            pltpu.VMEM((Hkv, group, 1), jnp.float32),
+        ],
+    )
+    kernel = functools.partial(_paged_decode_kernel, scale=scale,
+                               pages=pages, num_steps=num_steps)
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        # Copies started in one step are waited for in a later one.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        interpret=interpret,
+    )(bt, lens, q, k_pool, v_pool)
+    return out.reshape(B, 1, Hq, D)
+
+
+def _paged_decode_walk(q, k_pool, v_pool, bt, lens, scale, interpret):
+    """paged_decode for head dims off the lane width: grid (B, Hkv,
+    max_blocks), one (Bs, D) pool block of one kv head a step, fetched by
+    the BlockSpec pipeline along the table (``_walk``). q: (B, Hkv,
+    group, D); returns the same shape."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    B, Hkv, group, D = q.shape
+    NB, _, Bs, _ = k_pool.shape
+    max_blocks = bt.shape[1]
 
     def kv_map(b, h, j, bt, lens):
         return (_walk(bt, lens, b, j, Bs, NB), h, 0, 0)
@@ -101,13 +294,12 @@ def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
         out_specs=pl.BlockSpec((1, 1, group, D), q_map),
         scratch_shapes=softmax_scratch(group, D),
     )
-    out = pl.pallas_call(
+    return pl.pallas_call(
         lambda bt_ref, *refs: kernel(*refs),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, Hkv, group, D), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(bt, lens, q.reshape(B, Hkv, group, D), k_pool, v_pool)
-    return out.reshape(B, 1, Hq, D)
+    )(bt, lens, q, k_pool, v_pool)
 
 
 def _paged_prefill_kernel(bt_ref, len_ref, off_ref, q_ref, k_ref, v_ref,

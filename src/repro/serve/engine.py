@@ -67,6 +67,8 @@ from repro.core import (GLBParams, diffusion_pairs, fabric_summary,
                         lifeline_buddies, match_steals, merge_place_stats,
                         rewire_lifelines, terminated)
 from repro.core.autotune import paged_block_kv
+from repro.kernels.paged_decode import (paged_decode_grid,
+                                        paged_decode_live_steps)
 from repro.models import (decode_step, forward, make_cache,
                           make_paged_cache, sample_tokens)
 from repro.models.config import ModelConfig
@@ -397,6 +399,12 @@ class Engine:
             )
             self._prefill_paged, self._copy_block = _make_paged_fns(
                 cfg, max_seq, bs, temperature
+            )
+            # How paged_decode walks a slot's table (pools cast to the
+            # activations' dtype); None: no attention layer, no kernel.
+            self._decode_grid = (
+                (self.max_blocks, bs, cfg.hd, cfg.n_kv_heads, cfg.dtype)
+                if "k" in self.cache else None
             )
             self._prefill_chunk_fn = (
                 _make_chunk_fn(cfg, temperature)
@@ -962,9 +970,27 @@ class Engine:
             self.tracer.begin("drain", pid=self.replica_id)
         buf = np.asarray(buf)               # the single drain
         self.host_syncs += 1
+        if self.paged and self._decode_grid:
+            self._count_kv_steps(lens, buf >= 0)
         self._drain(buf)
         if traced:
             self.tracer.end(pid=self.replica_id)
+
+    def _count_kv_steps(self, lens: np.ndarray, emitted: np.ndarray):
+        """paged_decode grid steps of one dispatch, reckoned on the host
+        from the slots' lengths before it and the (steps_per_sync, slots)
+        mask of the tokens they emitted: a slot emits at each position it
+        decodes, at windows lens+1, lens+2, ... ``paged_decode_kv_steps``
+        counts every grid step of every position, ``..._live`` those that
+        read pool blocks."""
+        n = emitted.shape[0]
+        windows = np.where(
+            emitted, lens.astype(np.int64) + 1 + np.arange(n)[:, None], 0)
+        _, steps = paged_decode_grid(*self._decode_grid)
+        self.metrics.counter("paged_decode_kv_steps").inc(
+            n * self.max_slots * steps)
+        self.metrics.counter("paged_decode_kv_steps_live").inc(
+            paged_decode_live_steps(windows, *self._decode_grid))
 
     def _step_paged(self):
         if self.tracer.enabled:
@@ -1120,6 +1146,9 @@ class Engine:
                 adoptions=self.sched.adoptions,
                 chunks_scheduled=self.sched.chunks_scheduled,
                 peak_occupancy_pct=round(100 * self.peak_occupancy, 1),
+                paged_decode_pages_per_step=(
+                    paged_decode_grid(*self._decode_grid)[0]
+                    if self._decode_grid else 0),
             )
         if self.prefix_cache is not None:
             pc = self.prefix_cache

@@ -395,6 +395,81 @@ def test_backlogged_decode_counters_count_only_calls_with_a_queue(paged):
     assert snap["decode_slots_backlogged"] == sum(backlogged)
 
 
+def test_paged_decode_kv_step_counters_follow_slot_lengths():
+    """Each paged decode dispatch adds slots x grid steps a slot per
+    position to ``paged_decode_kv_steps``, and to ``..._live`` the steps
+    that the windows of the tokens each slot emitted reach (lens+1 ..
+    lens+k for k tokens); live never exceeds the total. The smoke model's
+    16-wide heads walk one block of one kv head a step."""
+    from repro.kernels.paged_decode import paged_decode_grid
+
+    e = Engine(CFG, PARAMS, paged=True, **KW)
+    P, steps = paged_decode_grid(*e._decode_grid)
+    assert (P, steps) == (1, CFG.n_kv_heads * e.max_blocks)
+    seen = []
+    decode = e._decode_n
+
+    def spy(params, tokens, cache, bt, lens, budget, key):
+        out = decode(params, tokens, cache, bt, lens, budget, key)
+        seen.append((np.asarray(lens).copy(), np.asarray(out[0]).copy()))
+        return out
+
+    e._decode_n = spy
+    _drive(e, [Request(rid=i, prompt=[3, i + 1, 4], max_new=6 + i)
+               for i in range(5)])
+    snap = e.stats()
+    n = e.steps_per_sync
+    assert seen and snap["paged_decode_pages_per_step"] == P
+    assert snap["paged_decode_kv_steps"] == (
+        len(seen) * n * e.max_slots * steps)
+    live = 0
+    for lens, buf in seen:
+        for i, L in enumerate(lens.tolist()):
+            k = int((buf[:, i] >= 0).sum())
+            assert k == 0 or L >= 0
+            live += CFG.n_kv_heads * sum(
+                -(-w // e.block_size) for w in range(L + 1, L + k + 1))
+    assert snap["paged_decode_kv_steps_live"] == live
+    assert 0 < live < snap["paged_decode_kv_steps"]
+
+
+def test_paged_decode_kv_steps_live_multi_step_table_and_idle():
+    """At a 128-lane head dim a grid step copies many pages of every kv
+    head: windows that cross a step edge inside the burst count the extra
+    step; a dispatch where no slot emitted counts no live step, only the
+    grid."""
+    import dataclasses
+
+    from repro.kernels.paged_decode import paged_decode_grid
+
+    cfg = dataclasses.replace(CFG, head_dim=128)
+    e = Engine(cfg, init_lm(jax.random.key(1), cfg), paged=True,
+               max_slots=4, max_seq=2048, pad_len=8, steps_per_sync=8,
+               block_size=8)
+    P, steps = paged_decode_grid(*e._decode_grid)
+    step_tokens = P * e.block_size
+    assert P > 1 and steps == e.max_blocks // P > 1
+    grid = 8 * 4 * steps
+    lens = np.array([-1, 0, step_tokens - 3, 2040], np.int32)
+    emitted = np.zeros((8, 4), bool)
+    emitted[:, 1] = emitted[:, 2] = True
+    emitted[:7, 3] = True       # slot 3 stops at max_seq - 1
+    e._count_kv_steps(lens, emitted)
+    snap = e.stats()
+    assert snap["paged_decode_pages_per_step"] == P
+    assert snap["paged_decode_kv_steps"] == grid
+    # slot 1: windows 1..8, one step each; slot 2: windows step_tokens-2
+    # .. step_tokens+5, the last 5 cross into a second step; slot 3:
+    # windows 2041..2047.
+    want = 8 + (3 + 5 * 2) + 7 * -(-2041 // step_tokens)
+    assert snap["paged_decode_kv_steps_live"] == want
+    e._count_kv_steps(np.array([0, 9, 100, 7], np.int32),
+                      np.zeros((8, 4), bool))
+    snap = e.stats()
+    assert snap["paged_decode_kv_steps"] == 2 * grid
+    assert snap["paged_decode_kv_steps_live"] == want
+
+
 def test_stats_is_registry_view_and_merge_superset():
     """Engine.stats() == metrics snapshot; merged fabric keys are a
     superset of every per-replica snapshot's keys (the satellite

@@ -25,22 +25,24 @@ def _pool(B, Hq, Hkv, D, dtype=jnp.float32, salt=0):
     return q, kp, vp
 
 
-def _tables(lens, salt=0):
+def _tables(lens, salt=0, num_blocks=NUM_BLOCKS, max_blocks=MAX_BLOCKS,
+            bs=BS):
     """Disjoint scrambled block tables; -1 garbage past each allocation."""
     rng = np.random.RandomState(salt)
-    perm = list(rng.permutation(NUM_BLOCKS))
-    bt = np.full((len(lens), MAX_BLOCKS), -1, np.int32)
+    perm = list(rng.permutation(num_blocks))
+    bt = np.full((len(lens), max_blocks), -1, np.int32)
     for b, L in enumerate(lens):
-        nblk = -(-L // BS) if L else 0
+        nblk = -(-L // bs) if L else 0
         bt[b, :nblk] = [perm.pop() for _ in range(nblk)]
     return bt
 
 
 def _gathered(kp, bt, b, L):
     """Sequence b's first L tokens in logical order, (L, Hkv, D), from a
-    head-major (num_blocks, Hkv, BS, D) pool."""
-    nblk = -(-L // BS)
-    blocks = np.asarray(kp)[bt[b, :nblk]].swapaxes(1, 2)   # (n, BS, Hkv, D)
+    head-major (num_blocks, Hkv, Bs, D) pool."""
+    bs = kp.shape[2]
+    nblk = -(-L // bs)
+    blocks = np.asarray(kp)[bt[b, :nblk]].swapaxes(1, 2)   # (n, Bs, Hkv, D)
     return blocks.reshape(-1, kp.shape[1], kp.shape[3])[:L]
 
 
@@ -50,6 +52,25 @@ def test_paged_decode_matches_ref_window(Hq, Hkv, L):
     B, D = 2, 32
     q, kp, vp = _pool(B, Hq, Hkv, D, salt=L)
     bt = _tables([L] * B, salt=L)
+    out = paged_decode(q, kp, vp, jnp.asarray(bt),
+                       jnp.full((B,), L, jnp.int32), interpret=True)
+    for b in range(B):
+        kc = jnp.asarray(_gathered(kp, bt, b, L))[None]
+        vc = jnp.asarray(_gathered(vp, bt, b, L))[None]
+        want = ref.attention_ref(q[b:b + 1], kc, vc, causal=True)
+        np.testing.assert_allclose(np.asarray(out[b:b + 1]),
+                                   np.asarray(want), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("Hq,Hkv", [(4, 4), (8, 2)])  # MHA, GQA
+@pytest.mark.parametrize("L", [1, 17, 128])
+def test_paged_decode_lane_width_matches_ref_window(Hq, Hkv, L):
+    """At a 128-lane head dim the kernel copies pages (here the whole
+    8-entry table in one grid step) instead of walking one block a step:
+    the same windows, the same answers."""
+    B, D = 2, 128
+    q, kp, vp = _pool(B, Hq, Hkv, D, salt=L + 1)
+    bt = _tables([L] * B, salt=L + 1)
     out = paged_decode(q, kp, vp, jnp.asarray(bt),
                        jnp.full((B,), L, jnp.int32), interpret=True)
     for b in range(B):
@@ -75,6 +96,114 @@ def test_paged_decode_mixed_lengths_and_idle():
         want = ref.attention_ref(q[b:b + 1], kc, vc, causal=True)
         np.testing.assert_allclose(np.asarray(out[b:b + 1]),
                                    np.asarray(want), atol=2e-6, rtol=2e-6)
+
+
+# Qwen2-1.5B decode widths with a table long enough for several compute
+# steps: 48 blocks of 32 tokens, 16 pages (512 tokens) per grid step.
+QW_BS, QW_MAX_BLOCKS, QW_NUM_BLOCKS = 32, 48, 128
+
+
+@pytest.mark.parametrize("Hq,Hkv", [(12, 2), (4, 4)])  # GQA, MHA
+@pytest.mark.parametrize("lens", [
+    [1, 511, 512, 513],     # one token; ending on, and one past, a step edge
+    [1536, 0, 1025, 32],    # whole table; idle slot; three steps; one page
+])
+def test_paged_decode_multi_page_steps(Hq, Hkv, lens):
+    """Windows spanning several compute steps, scrambled tables, garbage
+    entries past each allocation that name NaN-filled blocks, NaN past
+    each window inside its last block, and an idle slot: the kernel
+    matches attention_ref on each gathered window, the idle slot emits
+    exact zeros, and no NaN reaches the result."""
+    from repro.kernels.paged_decode import paged_decode_grid
+
+    B, D = len(lens), 128
+    P, steps = paged_decode_grid(QW_MAX_BLOCKS, QW_BS, D, Hkv, jnp.float32)
+    assert 1 < P < QW_MAX_BLOCKS and steps == QW_MAX_BLOCKS // P
+    salt = sum(lens) + Hkv
+    ks = jax.random.split(jax.random.fold_in(KEY, salt), 3)
+    q = jax.random.normal(ks[0], (B, 1, Hq, D), jnp.float32)
+    shape = (QW_NUM_BLOCKS, Hkv, QW_BS, D)
+    kp = np.array(jax.random.normal(ks[1], shape, jnp.float32))
+    vp = np.array(jax.random.normal(ks[2], shape, jnp.float32))
+    bt = _tables(lens, salt=salt, num_blocks=QW_NUM_BLOCKS,
+                 max_blocks=QW_MAX_BLOCKS, bs=QW_BS)
+    unused = np.setdiff1d(np.arange(QW_NUM_BLOCKS), bt[bt >= 0])
+    for pool in (kp, vp):
+        pool[unused] = np.nan
+        for b, L in enumerate(lens):
+            if L % QW_BS:
+                pool[bt[b, L // QW_BS], :, L % QW_BS:] = np.nan
+    bt[bt < 0] = unused[0]
+    out = paged_decode(q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
+                       jnp.asarray(lens, jnp.int32), interpret=True)
+    assert np.isfinite(np.asarray(out)).all()
+    for b, L in enumerate(lens):
+        if L == 0:
+            assert float(jnp.abs(out[b]).max()) == 0.0
+            continue
+        kc = jnp.asarray(_gathered(kp, bt, b, L))[None]
+        vc = jnp.asarray(_gathered(vp, bt, b, L))[None]
+        want = ref.attention_ref(q[b:b + 1], kc, vc, causal=True)
+        np.testing.assert_allclose(np.asarray(out[b:b + 1]),
+                                   np.asarray(want), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("max_blocks,Bs,D,Hkv,itemsize", [
+    (128, 32, 128, 2, 2),    # Qwen2-1.5B serving pool, bf16
+    (128, 32, 128, 2, 4),
+    (64, 64, 64, 4, 2),      # TinyLlama head dim: the one-block walk
+    (256, 16, 112, 32, 4),   # Zamba2 shared attention: the one-block walk
+    (256, 16, 128, 32, 4),   # 32 kv heads at 128 lanes: VMEM-capped
+    (8, 16, 32, 2, 4),       # the tests' tiny pool: the one-block walk
+    (7, 128, 128, 8, 2),     # prime table: one page per step
+    (1, 32, 128, 2, 2),
+])
+def test_pages_per_step_divides_and_respects_caps(max_blocks, Bs, D, Hkv,
+                                                  itemsize):
+    """paged_decode_grid: at 128-lane head dims, the largest divisor of
+    max_blocks within the token and VMEM caps; any other head dim walks
+    one block of one kv head a step."""
+    from repro.kernels.paged_decode import (_KV_VMEM_BYTES, _STEP_TOKENS,
+                                            paged_decode_grid)
+
+    dtype = {2: jnp.bfloat16, 4: jnp.float32}[itemsize]
+    P, steps = paged_decode_grid(max_blocks, Bs, D, Hkv, dtype)
+    if D % 128:
+        assert (P, steps) == (1, Hkv * max_blocks)
+        return
+    assert P >= 1 and max_blocks % P == 0 and steps == max_blocks // P
+    page_bytes = Hkv * Bs * D * itemsize
+    assert P == 1 or P * Bs <= _STEP_TOKENS
+    assert P == 1 or 2 * 2 * P * page_bytes <= _KV_VMEM_BYTES
+    # The largest such divisor: the next one up breaks a cap.
+    bigger = [p for p in range(P + 1, max_blocks + 1) if max_blocks % p == 0]
+    if bigger:
+        p = bigger[0]
+        assert p * Bs > _STEP_TOKENS or 4 * p * page_bytes > _KV_VMEM_BYTES
+    if (max_blocks, Bs, D, Hkv) == (128, 32, 128, 2):
+        assert P == 16
+    if (max_blocks, Bs, D, Hkv) == (256, 16, 128, 32):
+        assert P == 4     # 4 x 4 x 256 KiB pages fill the 4 MiB budget
+
+
+@pytest.mark.parametrize("D", [64, 128])
+def test_paged_decode_live_steps_follow_windows(D):
+    """Live grid steps: a window reads ceil(window / (P * Bs)) steps of
+    every kv head at once, or ceil(window / Bs) blocks of each kv head on
+    the one-block walk; idle slots read none."""
+    from repro.kernels.paged_decode import (paged_decode_grid,
+                                            paged_decode_live_steps)
+
+    args = (48, 32, D, 2, jnp.float32)
+    P, steps = paged_decode_grid(*args)
+    windows = np.array([[0, 1, 32, 33], [511, 512, 513, 1536]])
+    per_step = P * 32
+    want = sum(-(-int(w) // per_step) for w in windows.ravel())
+    if D % 128:
+        want *= 2
+    assert paged_decode_live_steps(windows, *args) == want
+    assert paged_decode_live_steps(np.zeros((3, 4), int), *args) == 0
+    assert paged_decode_live_steps(np.full(4, 1536), *args) == 4 * steps
 
 
 def test_paged_ref_oracle_matches_contiguous_gather():

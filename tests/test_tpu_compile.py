@@ -84,6 +84,17 @@ def test_attention_kernel_compiles_for_v5e(kernel, width, dtype, one_chip):
 
 
 @pytest.mark.parametrize("dtype", sorted(DTYPES))
+def test_paged_decode_compiles_at_serving_shape(dtype, one_chip):
+    """The Qwen2-1.5B serving engine's decode call: 32 slots over a pool
+    of 2048 blocks of 32 tokens, 128-entry block tables (max_seq 4096),
+    so each slot's table spans several multi-page grid steps."""
+    dt, Hq, Hkv, D = DTYPES[dtype], *ATTN_WIDTHS["qwen2"]
+    pool = ((2048, Hkv, 32, D), dt)
+    _compile(paged_decode, one_chip, ((32, 1, Hq, D), dt), pool, pool,
+             ((32, 128), jnp.int32), ((32,), jnp.int32))
+
+
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
 @pytest.mark.parametrize("H,P", [(48, 64), (24, 128)])  # mamba2 headdims
 def test_ssd_compiles_for_v5e(H, P, dtype, one_chip):
     dt, N, T = DTYPES[dtype], 128, 512
