@@ -22,7 +22,7 @@ def _on_tpu() -> bool:
 
 def attention(q, k, v, *, causal=True, scale=None, impl: str = "auto",
               block_q: int = 128, block_k: int = 128, lengths=None,
-              block_tables=None, q_offset=None):
+              block_tables=None, q_offset=None, layer=None):
     """impl: auto | pallas | pallas_interpret | ref | chunked
           | decode | decode_interpret | decode_ref
           | paged | paged_interpret | paged_ref
@@ -33,19 +33,21 @@ def attention(q, k, v, *, causal=True, scale=None, impl: str = "auto",
     oracle elsewhere; the decode_* impls force one arm.
 
     `block_tables` ((B, max_blocks) i32) additionally marks k/v as flat
-    (num_blocks, Hkv, block_size, D) KV *pools* indirected per sequence
-    through the table (serve/kvpool.py): calls route to the paged
-    flash-decode kernel on TPU and the gather oracle elsewhere. Every
-    impl spelling is normalized so one config knob drives contiguous and
-    paged decode alike — the window mask and table walk are never
-    dropped.
+    KV *pools* indirected per sequence through the table
+    (serve/kvpool.py): calls route to the paged flash-decode kernel on
+    TPU and the gather oracle elsewhere. A decode call (Sq == 1) passes
+    the stacked pools (layers, num_blocks, Hkv, block_size, D) and the
+    i32 `layer` it reads. Every impl spelling is normalized so one config
+    knob drives contiguous and paged decode alike — the window mask and
+    table walk are never dropped.
 
     `q_offset` ((B,) i32, paged only) marks the call as a *chunked
     prefill*: q holds Sq tokens at absolute positions
     ``[q_offset, q_offset + Sq)`` attending causally to the pool window
     ``[0, lengths)`` — the kernel/oracle pair that lets a long admission
     prefill in budget-sized chunks against blocks earlier chunks (or a
-    prefix-cache hit) already wrote.
+    prefix-cache hit) already wrote. k/v are then one layer's pools
+    (num_blocks, Hkv, block_size, D).
     """
     if q_offset is not None and block_tables is None:
         raise ValueError(
@@ -85,11 +87,15 @@ def attention(q, k, v, *, causal=True, scale=None, impl: str = "auto",
             return paged_prefill(q, k, v, block_tables, lengths, q_offset,
                                  scale=scale,
                                  interpret=(impl == "paged_interpret"))
+        if layer is None:
+            raise ValueError("paged decode reads the stacked pools at a "
+                             "layer index: pass layer")
         if impl == "paged_ref":
             return ref.paged_decode_ref(q, k, v, block_tables, lengths,
-                                        scale=scale)
+                                        layer, scale=scale)
         assert impl in ("paged", "paged_interpret"), impl
-        return paged_decode(q, k, v, block_tables, lengths, scale=scale,
+        return paged_decode(q, k, v, block_tables, lengths, layer,
+                            scale=scale,
                             interpret=(impl == "paged_interpret"))
     is_decode = lengths is not None
     if is_decode:

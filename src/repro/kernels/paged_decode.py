@@ -7,18 +7,23 @@ attending causally to pool blocks ``[0, e)``).
 flash_decode.py streams a *contiguous* per-slot cache; here the cache is
 a flat pool of KV blocks ("pages") shared by every sequence
 (serve/kvpool.py) and each sequence names its pages through a block
-table. Two scalar-prefetch operands — ``block_tables`` (B, max_blocks)
-i32 and ``lengths`` (B,) i32 — arrive before the kernel body runs.
+table. ``paged_decode`` reads the serving engine's stacked pool, every
+layer's blocks in one array (layers, num_blocks, Hkv, Bs, D), at a layer
+index, so no caller slices a layer's pool out (or casts it) around the
+call. Three scalar-prefetch operands — ``block_tables`` (B, max_blocks)
+i32, ``lengths`` (B,) i32 and ``layer`` (1,) i32 — arrive before the
+kernel body runs.
 
 ``paged_decode`` decouples the compute block from the pool block: grid
 step ``(b, j)`` of the grid ``(batch, max_blocks // P)`` handles pages
 ``block_tables[b, j*P : (j+1)*P]`` of slot b for every kv head. The
-pools stay in HBM (``memory_space=pl.ANY``); the pool is head-major,
-(num_blocks, Hkv, Bs, D), so one async copy per page carries all kv
-heads into a double-buffered VMEM scratch (2, P, Hkv, Bs, D). Each live
-step starts the copies of the next live step (the same slot's next step,
-or step 0 of the next slot with a window) before waiting for its own, so
-copies overlap compute, across slots too. Gating:
+pools stay in HBM (``memory_space=pl.ANY``); each block is head-major,
+(Hkv, Bs, D), so one async copy per page (``pool.at[layer, page]``)
+carries all kv heads into a double-buffered VMEM scratch (2, P, Hkv, Bs,
+D) of the pool's dtype. Each live step starts the copies of the next live
+step (the same slot's next step, or step 0 of the next slot with a
+window) before waiting for its own, so copies overlap compute, across
+slots too. Gating:
 
   * dead steps — a step wholly past its slot's window copies and
     computes nothing; the last live step copies only its live pages, so
@@ -36,20 +41,22 @@ double-buffered k/v pages fit a 4 MiB VMEM budget.
 A head dim that is not a multiple of 128 lanes (TinyLlama 64, Zamba2
 112) keeps the one-block walk: grid (batch, kv_heads, max_blocks), one
 (Bs, D) block of one kv head a step through the BlockSpec pipeline
-(``_walk``, flash_decode.decode_kernel). Mosaic slices a ref only in
-whole 128-lane tiles, so the page copies cannot address such a pool, and
-padding it to the lane width would move the whole pool every call.
+(``_walk``, flash_decode.decode_kernel), whose index map leads with the
+layer. Mosaic slices a ref only in whole 128-lane tiles, so the page
+copies cannot address such a pool, and padding it to the lane width
+would move the whole pool every call.
 Oracle: ``ref.paged_decode_ref`` (gather blocks -> decode_ref). Routed
-via ``ops.attention(..., block_tables=...)``.
+via ``ops.attention(..., block_tables=..., layer=...)``.
 
-``paged_prefill`` walks one pool block a grid step (``_walk``), grid
-(batch, q_heads, max_blocks), with an (Sq, D) query block of one query
-head (kv head h // group) and a third scalar-prefetch operand
-``q_offset`` ((B,) i32 chunk start). Past-window steps clamp the logical
-block to the last live one, so the physical index repeats and the
-pipeline issues no DMA; ``pl.when`` skips their compute; the mask is
-causal-by-absolute-position (``kpos <= q_offset + i``) intersected with
-the ``lengths`` window. Oracle: ``ref.paged_prefill_ref``; routed via
+``paged_prefill`` takes one layer's pool (num_blocks, Hkv, Bs, D) and
+walks one pool block a grid step (``_walk``), grid (batch, q_heads,
+max_blocks), with an (Sq, D) query block of one query head (kv head
+h // group) and a third scalar-prefetch operand ``q_offset`` ((B,) i32
+chunk start). Past-window steps clamp the logical block to the last
+live one, so the physical index repeats and the pipeline issues no DMA;
+``pl.when`` skips their compute; the mask is causal-by-absolute-position
+(``kpos <= q_offset + i``) intersected with the ``lengths`` window.
+Oracle: ``ref.paged_prefill_ref``; routed via
 ``ops.attention(..., block_tables=..., q_offset=...)``.
 """
 from __future__ import annotations
@@ -113,17 +120,19 @@ def paged_decode_live_steps(windows, max_blocks, Bs, D, Hkv, dtype):
     return int((-(-w // (pages * Bs))).sum()) * heads
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
-                         k_buf, v_buf, sems, state, acc_ref, m_ref, l_ref,
-                         *, scale, pages, num_steps):
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, state, acc_ref, m_ref,
+                         l_ref, *, scale, pages, num_steps):
     """Grid step (b, j): pool pages ``[j*P, (j+1)*P)`` of slot b, every kv
-    head. ``state`` (SMEM) holds the k/v buffer the step's pages land in
-    and whether an earlier step already started their copies."""
+    head, of layer ``layer_ref[0]``. ``state`` (SMEM) holds the k/v
+    buffer the step's pages land in and whether an earlier step already
+    started their copies."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, j = pl.program_id(0), pl.program_id(1)
     B = pl.num_programs(0)
-    NB, Hkv, Bs, _ = k_hbm.shape
+    _, NB, Hkv, Bs, _ = k_hbm.shape
+    layer = layer_ref[0]
     bk = pages * Bs
 
     def live_pages(b, j):
@@ -136,7 +145,7 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
             page = jnp.clip(bt_ref[b, j * pages + i], 0, NB - 1)
             for hbm, vmem, s in ((k_hbm, k_buf, 0), (v_hbm, v_buf, 1)):
                 copy = pltpu.make_async_copy(
-                    hbm.at[page], vmem.at[buf, i], sems.at[s, buf]
+                    hbm.at[layer, page], vmem.at[buf, i], sems.at[s, buf]
                 )
                 copy.start() if start else copy.wait()
             return carry
@@ -205,17 +214,20 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_hbm, v_hbm, o_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
-def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
+def paged_decode(q, k_pool, v_pool, block_tables, lengths, layer, *,
                  scale: float | None = None, interpret: bool = False):
-    """q: (B, 1, Hq, D); k_pool, v_pool: (num_blocks, Hkv, Bs, D) flat
-    block pools; block_tables: (B, max_blocks) i32 physical block per
-    logical block (entries past the allocation may be any value — they
-    never reach the result); lengths: (B,) i32 visible window (0 => idle
-    slot, output zeros). Returns (B, 1, Hq, D)."""
+    """q: (B, 1, Hq, D); k_pool, v_pool: (layers, num_blocks, Hkv, Bs, D)
+    stacked block pools, any float dtype (pages are read in it and
+    upcast to f32 in VMEM); block_tables: (B, max_blocks) i32 physical
+    block per logical block (entries past the allocation may be any value
+    — they never reach the result); lengths: (B,) i32 visible window (0
+    => idle slot, output zeros); layer: i32 scalar, the pool layer read.
+    A caller holding one layer's pool passes ``pool[None]`` and layer 0.
+    Returns (B, 1, Hq, D)."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, Sq, Hq, D = q.shape
-    NB, Hkv, Bs, _ = k_pool.shape
+    _, NB, Hkv, Bs, _ = k_pool.shape
     assert Sq == 1, f"paged_decode is Sq==1 only, got {Sq}"
     assert Hq % Hkv == 0, (Hq, Hkv)
     group = Hq // Hkv
@@ -223,18 +235,19 @@ def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
     scale = float(scale) if scale is not None else 1.0 / np.sqrt(D)
     bt = jnp.asarray(block_tables, jnp.int32)
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
+    li = jnp.asarray(layer, jnp.int32).reshape(1)
     q = q.reshape(B, Hkv, group, D)
     if D % _LANES:
-        out = _paged_decode_walk(q, k_pool, v_pool, bt, lens, scale,
+        out = _paged_decode_walk(q, k_pool, v_pool, bt, lens, li, scale,
                                  interpret)
         return out.reshape(B, 1, Hq, D)
     pages, num_steps = paged_decode_grid(max_blocks, Bs, D, Hkv,
                                          k_pool.dtype)
 
-    slot_map = lambda b, j, bt, lens: (b, 0, 0, 0)
+    slot_map = lambda b, j, bt, lens, li: (b, 0, 0, 0)
     page_buf = pltpu.VMEM((2, pages, Hkv, Bs, D), k_pool.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, num_steps),
         in_specs=[
             pl.BlockSpec((1, Hkv, group, D), slot_map),
@@ -261,45 +274,45 @@ def paged_decode(q, k_pool, v_pool, block_tables, lengths, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary")),
         interpret=interpret,
-    )(bt, lens, q, k_pool, v_pool)
+    )(bt, lens, li, q, k_pool, v_pool)
     return out.reshape(B, 1, Hq, D)
 
 
-def _paged_decode_walk(q, k_pool, v_pool, bt, lens, scale, interpret):
+def _paged_decode_walk(q, k_pool, v_pool, bt, lens, li, scale, interpret):
     """paged_decode for head dims off the lane width: grid (B, Hkv,
-    max_blocks), one (Bs, D) pool block of one kv head a step, fetched by
-    the BlockSpec pipeline along the table (``_walk``). q: (B, Hkv,
-    group, D); returns the same shape."""
+    max_blocks), one (Bs, D) block of one kv head of layer ``li[0]`` a
+    step, fetched by the BlockSpec pipeline along the table (``_walk``).
+    q: (B, Hkv, group, D); returns the same shape."""
     from jax.experimental.pallas import tpu as pltpu
 
     B, Hkv, group, D = q.shape
-    NB, _, Bs, _ = k_pool.shape
+    _, NB, _, Bs, _ = k_pool.shape
     max_blocks = bt.shape[1]
 
-    def kv_map(b, h, j, bt, lens):
-        return (_walk(bt, lens, b, j, Bs, NB), h, 0, 0)
+    def kv_map(b, h, j, bt, lens, li):
+        return (li[0], _walk(bt, lens, b, j, Bs, NB), h, 0, 0)
 
-    q_map = lambda b, h, j, bt, lens: (b, h, 0, 0)
+    q_map = lambda b, h, j, bt, lens, li: (b, h, 0, 0)
     kernel = functools.partial(
         decode_kernel, scale=scale, block_k=Bs, num_kv=max_blocks
     )
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(B, Hkv, max_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, group, D), q_map),
-            pl.BlockSpec((1, 1, Bs, D), kv_map),
-            pl.BlockSpec((1, 1, Bs, D), kv_map),
+            pl.BlockSpec((None, 1, 1, Bs, D), kv_map),
+            pl.BlockSpec((None, 1, 1, Bs, D), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, group, D), q_map),
         scratch_shapes=softmax_scratch(group, D),
     )
     return pl.pallas_call(
-        lambda bt_ref, *refs: kernel(*refs),
+        lambda bt_ref, len_ref, li_ref, *refs: kernel(len_ref, *refs),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         interpret=interpret,
-    )(bt, lens, q, k_pool, v_pool)
+    )(bt, lens, li, q, k_pool, v_pool)
 
 
 def _paged_prefill_kernel(bt_ref, len_ref, off_ref, q_ref, k_ref, v_ref,

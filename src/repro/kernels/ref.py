@@ -70,28 +70,29 @@ def decode_ref(q, k, v, lengths, scale: float | None = None):
     return o.reshape(B, 1, Hq, hd).astype(q.dtype)
 
 
-def paged_decode_ref(q, k_pool, v_pool, block_tables, lengths,
+def paged_decode_ref(q, k_pool, v_pool, block_tables, lengths, layer,
                      scale: float | None = None):
     """Sq==1 attention against a block-table-indirected KV pool (the
-    paged_decode oracle): gather each sequence's blocks into its logical
-    order, then decode_ref with the same window mask. Garbage table
-    entries past the allocation are clamped into the pool — the window
-    mask keeps their rows invisible.
+    paged_decode oracle): gather each sequence's blocks of pool layer
+    ``layer`` into their logical order, then decode_ref with the same
+    window mask. Garbage table entries past the allocation are clamped
+    into the pool — the window mask keeps their rows invisible.
 
-    q (B,1,Hq,hd); k_pool/v_pool (num_blocks, Hkv, Bs, hd);
-    block_tables (B, max_blocks) i32; lengths (B,) i32."""
-    k = gather_pool(k_pool, block_tables)
-    v = gather_pool(v_pool, block_tables)
+    q (B,1,Hq,hd); k_pool/v_pool (layers, num_blocks, Hkv, Bs, hd);
+    block_tables (B, max_blocks) i32; lengths (B,) i32; layer i32."""
+    k = gather_pool(k_pool, block_tables, layer)
+    v = gather_pool(v_pool, block_tables, layer)
     return decode_ref(q, k, v, lengths, scale=scale)
 
 
-def gather_pool(pool, block_tables):
-    """Each sequence's pool blocks in logical order as a contiguous
-    (B, max_blocks * Bs, Hkv, hd) cache; pool (num_blocks, Hkv, Bs, hd).
-    Garbage table entries are clamped into the pool."""
-    NB, Hkv, Bs, hd = pool.shape
+def gather_pool(pool, block_tables, layer):
+    """Each sequence's blocks of pool layer ``layer`` in logical order as
+    a contiguous (B, max_blocks * Bs, Hkv, hd) cache, in one gather;
+    pool (layers, num_blocks, Hkv, Bs, hd). Garbage table entries are
+    clamped into the pool."""
+    _, NB, Hkv, Bs, hd = pool.shape
     bt = jnp.clip(jnp.asarray(block_tables, jnp.int32), 0, NB - 1)
-    blocks = jnp.swapaxes(pool[bt], 2, 3)       # (B, max_blocks, Bs, Hkv, hd)
+    blocks = jnp.swapaxes(pool[layer, bt], 2, 3)  # (B, blocks, Bs, Hkv, hd)
     return blocks.reshape(bt.shape[0], -1, Hkv, hd)
 
 
@@ -112,8 +113,8 @@ def paged_prefill_ref(q, k_pool, v_pool, block_tables, lengths, q_offset,
     Hkv = k_pool.shape[1]
     group = Hq // Hkv
     scale = scale if scale is not None else 1.0 / np.sqrt(hd)
-    k = gather_pool(k_pool, block_tables)       # logical order gather
-    v = gather_pool(v_pool, block_tables)
+    k = gather_pool(k_pool[None], block_tables, 0)   # logical order
+    v = gather_pool(v_pool[None], block_tables, 0)
     S = k.shape[1]
     lens = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32), (B,))
     offs = jnp.broadcast_to(jnp.asarray(q_offset, jnp.int32), (B,))

@@ -46,12 +46,16 @@ def attn_fwd(
     x,                       # (B, S, D)
     positions,               # (B, S) or (B, S, 3) for mrope
     cfg: ModelConfig,
-    cache: Optional[dict] = None,   # {"k","v"}: (B, S_max, Hkv, hd), or
-                                    # paged pools (NB, Hkv, Bs, hd)
+    cache: Optional[dict] = None,   # {"k","v"}: (B, S_max, Hkv, hd); paged
+                                    # prefill: pools (NB, Hkv, Bs, hd);
+                                    # paged decode: the stacked pools
+                                    # (layers, NB, Hkv, Bs, hd)
     cache_len=None,          # i32 scalar: valid entries in cache
     mode: str = "train",     # train | prefill | decode
     block_tables=None,       # (B, max_blocks) i32: decode against paged
                              # pools instead of a contiguous cache
+    layer=None,              # i32: the pool layer a paged decode updates
+                             # and reads, in place in the stacked pools
 ) -> Tuple[jax.Array, Optional[dict]]:
     B, S, D = x.shape
     Hq, Hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -82,10 +86,15 @@ def attn_fwd(
         if block_tables is not None:
             # Paged cache: token at logical position lens[b] lands in
             # physical block block_tables[b, lens[b] // Bs] at offset
-            # lens[b] % Bs. The scheduler guarantees that block is
-            # allocated and exclusively owned (COW resolved); idle slots
-            # write out-of-bounds and are dropped.
-            NB, Bs = cache["k"].shape[0], cache["k"].shape[2]
+            # lens[b] % Bs of pool layer ``layer``. The scheduler
+            # guarantees that block is allocated and exclusively owned
+            # (COW resolved); idle slots write out-of-bounds and are
+            # dropped. One scatter into the stacked pools, and the kernel
+            # reads its layer where it lies: no pool-sized copy or cast.
+            # The scatter writes one D-wide row per (slot, kv head): with
+            # the whole (Hkv, D) window as its update, XLA relayouts the
+            # pool for the scatter and copies it back for the kernel.
+            NB, Bs = cache["k"].shape[1], cache["k"].shape[3]
             bt = jnp.asarray(block_tables, jnp.int32)
             pos = jnp.maximum(lens, 0)
             phys = jnp.take_along_axis(
@@ -93,15 +102,17 @@ def attn_fwd(
             )[:, 0]
             phys = jnp.where(lens >= 0, phys, NB)   # OOB => dropped
             off = pos % Bs
-            ck = cache["k"].at[phys, :, off].set(
+            at = (layer, phys[:, None], jnp.arange(Hkv)[None, :],
+                  off[:, None])
+            ck = cache["k"].at[at].set(
                 k[:, 0].astype(cache["k"].dtype), mode="drop"
             )
-            cv = cache["v"].at[phys, :, off].set(
+            cv = cache["v"].at[at].set(
                 v[:, 0].astype(cache["v"].dtype), mode="drop"
             )
             o = ops.attention(
-                q, ck.astype(dt), cv.astype(dt), causal=False,
-                impl=cfg.decode_impl, lengths=window, block_tables=bt,
+                q, ck, cv, causal=False, impl=cfg.decode_impl,
+                lengths=window, block_tables=bt, layer=layer,
             ).astype(dt)
             new_cache = {"k": ck, "v": cv}
         else:

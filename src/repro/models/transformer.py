@@ -149,19 +149,37 @@ def _update_cache(cache, keys, idx, new):
     return out
 
 
+def _attn(cfg, ap, x, positions, cache, idx, cache_len, mode, block_tables):
+    """Attention of cache layer ``idx``. A paged decode hands the stacked
+    k/v pools and ``idx`` to attn_fwd, which writes its token and reads
+    its window in place; every other mode slices layer ``idx`` out of the
+    stacked cache and writes it back. Returns (h, cache)."""
+    in_place = mode == "decode" and block_tables is not None
+    if not cache:
+        acache = None
+    elif in_place:
+        acache = {"k": cache["k"], "v": cache["v"]}
+    else:
+        acache = _slice_cache(cache, ("k", "v"), idx)
+    h, new_kv = attn_fwd(
+        ap, x, positions, cfg, cache=acache, cache_len=cache_len, mode=mode,
+        block_tables=block_tables, layer=idx if in_place else None,
+    )
+    if cache and new_kv is not None:
+        cache = ({**cache, **new_kv} if in_place
+                 else _update_cache(cache, ("k", "v"), idx, new_kv))
+    return h, cache
+
+
 # --------------------------------------------------------------- blocks
 def _apply_shared_block(cfg, sp, x, positions, cache, app_idx, cache_len,
                         mode, block_tables=None):
     """Zamba2's weight-shared attention+MLP block."""
-    h, new_kv = attn_fwd(
-        sp["attn"], rmsnorm(x, sp["ln1"], cfg.norm_eps), positions, cfg,
-        cache=None if not cache else _slice_cache(cache, ("k", "v"), app_idx),
-        cache_len=cache_len, mode=mode, block_tables=block_tables,
-    )
+    h, cache = _attn(cfg, sp["attn"], rmsnorm(x, sp["ln1"], cfg.norm_eps),
+                     positions, cache, app_idx, cache_len, mode,
+                     block_tables)
     x = x + h
     x = x + mlp_fwd(sp["mlp"], rmsnorm(x, sp["ln2"], cfg.norm_eps), x.dtype)
-    if cache and new_kv is not None:
-        cache = _update_cache(cache, ("k", "v"), app_idx, new_kv)
     return x, cache
 
 
@@ -199,12 +217,8 @@ def _apply_block(cfg, bp, shared, li, x, positions, cache, cache_len, mode,
             x, cache = jax.lax.cond(is_app, yes, lambda a: a, (x, cache))
         return x, cache, aux
 
-    acache = _slice_cache(cache, ("k", "v"), li) if cache else None
-    h, new_kv = attn_fwd(
-        bp["attn"], rmsnorm(x, bp["ln1"], cfg.norm_eps), positions, cfg,
-        cache=acache, cache_len=cache_len, mode=mode,
-        block_tables=block_tables,
-    )
+    h, cache = _attn(cfg, bp["attn"], rmsnorm(x, bp["ln1"], cfg.norm_eps),
+                     positions, cache, li, cache_len, mode, block_tables)
     x = x + h
     hin = rmsnorm(x, bp["ln2"], cfg.norm_eps)
     if cfg.family == "moe":
@@ -215,8 +229,6 @@ def _apply_block(cfg, bp, shared, li, x, positions, cache, cache_len, mode,
     else:
         h = mlp_fwd(bp["mlp"], hin, x.dtype)
     x = x + h
-    if cache and new_kv is not None:
-        cache = _update_cache(cache, ("k", "v"), li, new_kv)
     return x, cache, aux
 
 

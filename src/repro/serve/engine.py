@@ -56,6 +56,7 @@ into one fabric report.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import deque
 from typing import List, Optional
 
@@ -125,13 +126,15 @@ class Migration:
 
 # Module-level jits (NOT per-engine closures): every engine with the same
 # cache/buffer shapes shares one compiled gather/scatter, so a fabric of N
-# replicas compiles the migration path once, not N times.
+# replicas compiles the migration path once, not N times. Like every jit
+# that writes the pools, the scatter donates them: it updates them in
+# place, and the caller keeps only what it returns.
 @jax.jit
 def _gather_kv(cache_k, cache_v, ids):
     return cache_k[:, ids], cache_v[:, ids]
 
 
-@jax.jit
+@functools.partial(jax.jit, donate_argnums=(0, 1))
 def _scatter_kv(cache_k, cache_v, ids, bk, bv):
     return (cache_k.at[:, ids].set(bk.astype(cache_k.dtype)),
             cache_v.at[:, ids].set(bv.astype(cache_v.dtype)))
@@ -155,12 +158,13 @@ def _make_decode_loop(cfg: ModelConfig, max_seq: int, steps_per_sync: int,
     diverge between the two)."""
     vocab = cfg.vocab
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames=("cache",))
     def decode_tokens(params, tokens, cache, bt, lens, budget, key):
         """steps_per_sync decode steps entirely on device. Carries per-slot
         done masks (idle: lens < 0; finished: budget == 0) and fills an
         (N, slots) token buffer (-1 where a slot emitted nothing) that the
-        host drains with one sync."""
+        host drains with one sync. The cache is donated: each step writes
+        its tokens into it in place."""
         B = tokens.shape[0]
         buf = jnp.full((steps_per_sync, B), -1, jnp.int32)
 
@@ -224,14 +228,15 @@ def _make_paged_fns(cfg: ModelConfig, max_seq: int, block_size: int,
     vocab = cfg.vocab
     max_blocks = max_seq // block_size
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames=("cache", "row"))
     def prefill_paged(params, tokens, cache, bt_scatter, slot, row,
                       true_len, key):
         """Prefill into the reused row cache, then scatter the row's KV
         blocks into the pool through ``bt_scatter`` ((max_blocks,) i32,
         out-of-bounds sentinel past the prompt's blocks => dropped).
         Recurrent conv/ssm leaves stay slot-dense and write at ``slot``.
-        Retraces once per prompt bucket length (tokens.shape[1])."""
+        Pool and row are donated and updated in place. Retraces once per
+        prompt bucket length (tokens.shape[1])."""
         logits, row, _ = forward(
             params, cfg, tokens=tokens, cache=_scrub_row(row),
             cache_len=jnp.int32(0), mode="prefill",
@@ -254,10 +259,11 @@ def _make_paged_fns(cfg: ModelConfig, max_seq: int, block_size: int,
         )
         return first, cache, row
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames=("cache",))
     def copy_block(cache, src, dst):
-        """Apply one COW copy: physical block dst := src in the k/v
-        pools (recurrent slot state is never shared, nothing to copy)."""
+        """Apply one COW copy, in place in the donated pools: physical
+        block dst := src in the k/v pools (recurrent slot state is never
+        shared, nothing to copy)."""
         out = dict(cache)
         for name in ("k", "v"):
             if name in cache:
@@ -273,10 +279,11 @@ def _make_chunk_fn(cfg: ModelConfig, temperature: float):
     attending to the paged window [0, start+C) (paged_prefill kernel /
     oracle). Chunk shapes are exact (no bucket padding), so this retraces
     once per distinct chunk length. Returns the sampled token from the
-    chunk's last position — callers use it only on the final chunk."""
+    chunk's last position — callers use it only on the final chunk. The
+    pools are donated and written in place."""
     vocab = cfg.vocab
 
-    @jax.jit
+    @functools.partial(jax.jit, donate_argnames=("cache",))
     def prefill_chunk(params, tokens, cache, bt, start, key):
         logits, cache, _ = forward(
             params, cfg, tokens=tokens, cache=cache, cache_len=start,
@@ -400,10 +407,11 @@ class Engine:
             self._prefill_paged, self._copy_block = _make_paged_fns(
                 cfg, max_seq, bs, temperature
             )
-            # How paged_decode walks a slot's table (pools cast to the
-            # activations' dtype); None: no attention layer, no kernel.
+            # How paged_decode walks a slot's table: it copies pages in
+            # the pool's dtype. None: no attention layer, no kernel.
             self._decode_grid = (
-                (self.max_blocks, bs, cfg.hd, cfg.n_kv_heads, cfg.dtype)
+                (self.max_blocks, bs, cfg.hd, cfg.n_kv_heads,
+                 self.cache["k"].dtype)
                 if "k" in self.cache else None
             )
             self._prefill_chunk_fn = (

@@ -1,8 +1,9 @@
 """Paged decode alignment contract: the block-table-walking Pallas kernel
 (interpret mode) and the gather oracle must match ref.attention_ref on
 each sequence's logically-ordered visible window, across scrambled block
-tables, garbage entries past the allocation, head layouts (MHA/GQA), and
-idle slots."""
+tables, garbage entries past the allocation, head layouts (MHA/GQA),
+idle slots, and the layer a stacked (layers, blocks, ...) pool is read
+at. A caller holding one layer's pool passes ``pool[None]`` and layer 0."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -52,8 +53,8 @@ def test_paged_decode_matches_ref_window(Hq, Hkv, L):
     B, D = 2, 32
     q, kp, vp = _pool(B, Hq, Hkv, D, salt=L)
     bt = _tables([L] * B, salt=L)
-    out = paged_decode(q, kp, vp, jnp.asarray(bt),
-                       jnp.full((B,), L, jnp.int32), interpret=True)
+    out = paged_decode(q, kp[None], vp[None], jnp.asarray(bt),
+                       jnp.full((B,), L, jnp.int32), 0, interpret=True)
     for b in range(B):
         kc = jnp.asarray(_gathered(kp, bt, b, L))[None]
         vc = jnp.asarray(_gathered(vp, bt, b, L))[None]
@@ -71,8 +72,8 @@ def test_paged_decode_lane_width_matches_ref_window(Hq, Hkv, L):
     B, D = 2, 128
     q, kp, vp = _pool(B, Hq, Hkv, D, salt=L + 1)
     bt = _tables([L] * B, salt=L + 1)
-    out = paged_decode(q, kp, vp, jnp.asarray(bt),
-                       jnp.full((B,), L, jnp.int32), interpret=True)
+    out = paged_decode(q, kp[None], vp[None], jnp.asarray(bt),
+                       jnp.full((B,), L, jnp.int32), 0, interpret=True)
     for b in range(B):
         kc = jnp.asarray(_gathered(kp, bt, b, L))[None]
         vc = jnp.asarray(_gathered(vp, bt, b, L))[None]
@@ -85,8 +86,8 @@ def test_paged_decode_mixed_lengths_and_idle():
     q, kp, vp = _pool(4, 8, 2, 64, salt=99)
     lens = [1, 40, 0, 128]               # slot 2 idle
     bt = _tables(lens, salt=99)
-    out = paged_decode(q, kp, vp, jnp.asarray(bt),
-                       jnp.asarray(lens, jnp.int32), interpret=True)
+    out = paged_decode(q, kp[None], vp[None], jnp.asarray(bt),
+                       jnp.asarray(lens, jnp.int32), 0, interpret=True)
     assert float(jnp.abs(out[2]).max()) == 0.0  # idle emits exact zeros
     for b, L in enumerate(lens):
         if L == 0:
@@ -134,8 +135,9 @@ def test_paged_decode_multi_page_steps(Hq, Hkv, lens):
             if L % QW_BS:
                 pool[bt[b, L // QW_BS], :, L % QW_BS:] = np.nan
     bt[bt < 0] = unused[0]
-    out = paged_decode(q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-                       jnp.asarray(lens, jnp.int32), interpret=True)
+    out = paged_decode(q, jnp.asarray(kp)[None], jnp.asarray(vp)[None],
+                       jnp.asarray(bt), jnp.asarray(lens, jnp.int32), 0,
+                       interpret=True)
     assert np.isfinite(np.asarray(out)).all()
     for b, L in enumerate(lens):
         if L == 0:
@@ -146,6 +148,43 @@ def test_paged_decode_multi_page_steps(Hq, Hkv, lens):
         want = ref.attention_ref(q[b:b + 1], kc, vc, causal=True)
         np.testing.assert_allclose(np.asarray(out[b:b + 1]),
                                    np.asarray(want), atol=2e-6, rtol=2e-6)
+
+
+@pytest.mark.parametrize("layer", ["first", "last"])
+@pytest.mark.parametrize("dtype", ["f32", "bf16"])
+@pytest.mark.parametrize("D", [64, 112, 128])   # walk, walk, multi-page
+def test_paged_decode_stacked_pool_reads_its_layer(D, dtype, layer):
+    """A stacked pool of 3 layers read at layer 0 and at the last layer,
+    float32 and bf16 pools, the one-block walk (head dims 64, 112) and
+    the multi-page steps (128): the kernel matches the gather oracle on
+    that layer, the idle slot emits exact zeros, and every other layer,
+    all NaN, never reaches the result."""
+    L, Hq, Hkv = 3, 4, 2
+    lens = [1, 511, 0, 1025]             # one token; a step edge; idle
+    li = 0 if layer == "first" else L - 1
+    dt = {"f32": jnp.float32, "bf16": jnp.bfloat16}[dtype]
+    B = len(lens)
+    ks = jax.random.split(jax.random.fold_in(KEY, D + li), 3)
+    q = jax.random.normal(ks[0], (B, 1, Hq, D), dt)
+    shape = (L, QW_NUM_BLOCKS, Hkv, QW_BS, D)
+    pools = []
+    for k in ks[1:]:
+        pool = np.full(shape, np.nan, np.float32)
+        pool[li] = np.asarray(jax.random.normal(k, shape[1:], jnp.float32))
+        pools.append(jnp.asarray(pool, dt))
+    kp, vp = pools
+    bt = jnp.asarray(_tables(lens, salt=D, num_blocks=QW_NUM_BLOCKS,
+                             max_blocks=QW_MAX_BLOCKS, bs=QW_BS))
+    lengths = jnp.asarray(lens, jnp.int32)
+    out = paged_decode(q, kp, vp, bt, lengths, li, interpret=True)
+    want = ref.paged_decode_ref(q, kp, vp, bt, lengths, li)
+    assert np.isfinite(np.asarray(out, np.float32)).all()
+    assert float(jnp.abs(out[2]).max()) == 0.0
+    tol = 2e-5 if dtype == "f32" else 1e-2
+    live = [b for b, n in enumerate(lens) if n]
+    np.testing.assert_allclose(np.asarray(out, np.float32)[live],
+                               np.asarray(want, np.float32)[live],
+                               atol=tol, rtol=tol)
 
 
 @pytest.mark.parametrize("max_blocks,Bs,D,Hkv,itemsize", [
@@ -212,8 +251,8 @@ def test_paged_ref_oracle_matches_contiguous_gather():
     q, kp, vp = _pool(3, 8, 2, 32, salt=7)
     lens = [7, 33, 128]
     bt = _tables(lens, salt=7)
-    out = ref.paged_decode_ref(q, kp, vp, jnp.asarray(bt),
-                               jnp.asarray(lens, jnp.int32))
+    out = ref.paged_decode_ref(q, kp[None], vp[None], jnp.asarray(bt),
+                               jnp.asarray(lens, jnp.int32), 0)
     for b, L in enumerate(lens):
         kc = jnp.asarray(_gathered(kp, bt, b, L))[None]
         vc = jnp.asarray(_gathered(vp, bt, b, L))[None]
@@ -230,8 +269,8 @@ def test_shared_prefix_blocks_between_sequences():
     bt[0, :2] = [5, 9]
     bt[1, :3] = [5, 9, 17]               # shares blocks 5, 9 with seq 0
     lens = [32, 40]
-    out = paged_decode(q, kp, vp, jnp.asarray(bt),
-                       jnp.asarray(lens, jnp.int32), interpret=True)
+    out = paged_decode(q, kp[None], vp[None], jnp.asarray(bt),
+                       jnp.asarray(lens, jnp.int32), 0, interpret=True)
     for b, L in enumerate(lens):
         kc = jnp.asarray(_gathered(kp, bt, b, L))[None]
         vc = jnp.asarray(_gathered(vp, bt, b, L))[None]
@@ -243,24 +282,24 @@ def test_shared_prefix_blocks_between_sequences():
 def test_ops_attention_routes_paged_impls():
     """ops.attention with block_tables: every impl spelling lands on a
     table-walking path (kernel or oracle), and they agree; the window
-    mask / table walk can never be dropped."""
+    mask / table walk can never be dropped; a decode names its layer."""
     q, kp, vp = _pool(2, 4, 2, 32, salt=21)
+    kp, vp = kp[None], vp[None]
     lens = jnp.asarray([20, 100], jnp.int32)
     bt = jnp.asarray(_tables([20, 100], salt=21))
-    o_kernel = ops.attention(q, kp, vp, lengths=lens, block_tables=bt,
-                             impl="pallas_interpret")
-    o_ref = ops.attention(q, kp, vp, lengths=lens, block_tables=bt,
-                          impl="ref")
-    o_auto = ops.attention(q, kp, vp, lengths=lens, block_tables=bt,
-                           impl="auto")
-    o_decode = ops.attention(q, kp, vp, lengths=lens, block_tables=bt,
-                             impl="decode_ref")
+    kw = dict(lengths=lens, block_tables=bt, layer=0)
+    o_kernel = ops.attention(q, kp, vp, impl="pallas_interpret", **kw)
+    o_ref = ops.attention(q, kp, vp, impl="ref", **kw)
+    o_auto = ops.attention(q, kp, vp, impl="auto", **kw)
+    o_decode = ops.attention(q, kp, vp, impl="decode_ref", **kw)
     np.testing.assert_allclose(np.asarray(o_kernel), np.asarray(o_ref),
                                atol=2e-5, rtol=2e-5)
     np.testing.assert_array_equal(np.asarray(o_auto), np.asarray(o_ref))
     np.testing.assert_array_equal(np.asarray(o_decode), np.asarray(o_ref))
     with pytest.raises(ValueError):
         ops.attention(q, kp, vp, block_tables=bt)  # tables need lengths
+    with pytest.raises(ValueError):                 # a decode needs a layer
+        ops.attention(q, kp, vp, lengths=lens, block_tables=bt)
 
 
 # ----------------------------------------------------- chunked prefill
@@ -366,7 +405,8 @@ def test_paged_prefill_q_offset_one_token_equals_decode():
     q, kp, vp = _pool(B, Hq, Hkv, D, salt=5)
     bt = _tables(L, salt=5)
     lens = jnp.asarray(L, jnp.int32)
-    o_dec = ref.paged_decode_ref(q, kp, vp, jnp.asarray(bt), lens)
+    o_dec = ref.paged_decode_ref(q, kp[None], vp[None], jnp.asarray(bt),
+                                 lens, 0)
     o_pre = ref.paged_prefill_ref(q, kp, vp, jnp.asarray(bt), lens,
                                   lens - 1)
     np.testing.assert_allclose(np.asarray(o_dec), np.asarray(o_pre),

@@ -197,3 +197,85 @@ def test_balancer_completes_all_requests_paged():
     assert all(r.done for r in reqs)
     assert bal.moves > 0
     assert all(e.pool.free_blocks == e.pool.num_blocks for e in engines)
+
+
+# ------------------------------------------------------------- donation
+def _spy(engine, name, log):
+    """Wrap ``engine.<name>``, logging the k/v pools (and prefill's row)
+    each call was handed."""
+    fn = getattr(engine, name)
+
+    def spy(*args):
+        log.extend(a[n] for a in args if isinstance(a, dict)
+                   for n in ("k", "v") if n in a)
+        return fn(*args)
+
+    setattr(engine, name, spy)
+
+
+def test_pool_writing_calls_donate_their_pools():
+    """An admission (prefill into the pool) and a decode burst each take
+    the pools (and prefill the row cache) as donated buffers: what they
+    were handed is deleted once they return, and the engine holds only
+    the live arrays they returned."""
+    e = Engine(CFG, PARAMS, paged=True, block_size=8, max_slots=2,
+               max_seq=32, pad_len=8, steps_per_sync=4)
+    handed = {"prefill": [], "decode": []}
+    _spy(e, "_prefill_paged", handed["prefill"])
+    _spy(e, "_decode_n", handed["decode"])
+    req = Request(rid=0, prompt=[3, 1, 4, 2], max_new=10)
+    e.submit(req)
+    e.step()                            # admit (prefill), then one burst
+    assert len(handed["prefill"]) == 4      # pool k, v; row k, v
+    assert len(handed["decode"]) == 2
+    assert all(a.is_deleted() for a in handed["prefill"] + handed["decode"])
+    live = [e.cache["k"], e.cache["v"], e._row["k"], e._row["v"]]
+    assert not any(a.is_deleted() for a in live)
+    while e.load:
+        e.step()
+    assert req.done and not e.cache["k"].is_deleted()
+
+
+def test_donated_pools_keep_tokens_through_cow_migration_and_radix_hit(
+        monkeypatch):
+    """On donated pools, a radix-cache hit with its copy-on-write block
+    copies and a live migration into a peer's pool give the tokens the
+    contiguous engine gives; the copies and the migration's scatter
+    donate what they were handed."""
+    import repro.serve.engine as engine_mod
+
+    kw = dict(max_slots=2, max_seq=64, pad_len=16, steps_per_sync=4)
+    prompt = [7, 3, 9, 2, 5, 8, 6, 4, 1, 2, 3, 4]     # 1.5 blocks of 8
+    mk = lambda base: [Request(rid=base + i, prompt=list(prompt),
+                               max_new=6 + 8 * i) for i in range(2)]
+    want = _contiguous_baseline(lambda: mk(0), **kw)
+    e0 = Engine(CFG, PARAMS, paged=True, block_size=8, prefix_cache=True,
+                **kw)
+    e1 = Engine(CFG, PARAMS, paged=True, block_size=8, **kw)
+    copied, scattered = [], []
+    _spy(e0, "_copy_block", copied)
+    scatter = engine_mod._scatter_kv
+
+    def spy_scatter(ck, cv, *rest):
+        scattered.extend([ck, cv])
+        return scatter(ck, cv, *rest)
+
+    monkeypatch.setattr(engine_mod, "_scatter_kv", spy_scatter)
+    assert _run(e0, mk(0)) == want             # misses, fills the tree
+    second = mk(100)
+    for r in second:
+        e0.submit(r)
+    e0.step()                   # both hit the cached prefix, COW the tail
+    assert e0.prefix_cache.hits >= 2 and copied
+    slot = e0.migratable_slots()[0]
+    assert e1.migrate_in(e0.migrate_out(slot)) == "live"
+    assert scattered
+    guard = 0
+    while (e0.load or e1.load) and guard < 200:
+        e0.step()
+        e1.step()
+        guard += 1
+    assert [list(r.out) for r in second] == want
+    assert all(a.is_deleted() for a in copied + scattered)
+    for e in (e0, e1):
+        assert not (e.cache["k"].is_deleted() or e.cache["v"].is_deleted())

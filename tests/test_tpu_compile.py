@@ -68,6 +68,7 @@ def test_attention_kernel_compiles_for_v5e(kernel, width, dtype, one_chip):
     dt = DTYPES[dtype]
     i32 = jnp.int32
     pool = ((POOL_BLOCKS, Hkv, BLOCK, D), dt)
+    stacked = ((2, POOL_BLOCKS, Hkv, BLOCK, D), dt)     # (layers, ...)
     if kernel == "flash_attention":
         _compile(flash_attention, one_chip, ((1, PROMPT, Hq, D), dt),
                  ((1, PROMPT, Hkv, D), dt), ((1, PROMPT, Hkv, D), dt))
@@ -76,8 +77,8 @@ def test_attention_kernel_compiles_for_v5e(kernel, width, dtype, one_chip):
                  ((SLOTS, CACHE, Hkv, D), dt), ((SLOTS, CACHE, Hkv, D), dt),
                  ((SLOTS,), i32))
     elif kernel == "paged_decode":
-        _compile(paged_decode, one_chip, ((SLOTS, 1, Hq, D), dt), pool,
-                 pool, ((SLOTS, TABLE), i32), ((SLOTS,), i32))
+        _compile(paged_decode, one_chip, ((SLOTS, 1, Hq, D), dt), stacked,
+                 stacked, ((SLOTS, TABLE), i32), ((SLOTS,), i32), ((), i32))
     else:
         _compile(paged_prefill, one_chip, ((1, 200, Hq, D), dt), pool, pool,
                  ((1, TABLE), i32), ((1,), i32), ((1,), i32))
@@ -85,13 +86,14 @@ def test_attention_kernel_compiles_for_v5e(kernel, width, dtype, one_chip):
 
 @pytest.mark.parametrize("dtype", sorted(DTYPES))
 def test_paged_decode_compiles_at_serving_shape(dtype, one_chip):
-    """The Qwen2-1.5B serving engine's decode call: 32 slots over a pool
-    of 2048 blocks of 32 tokens, 128-entry block tables (max_seq 4096),
-    so each slot's table spans several multi-page grid steps."""
+    """The Qwen2-1.5B serving engine's decode call: 32 slots over a
+    stacked pool of 28 layers of 2048 blocks of 32 tokens, 128-entry
+    block tables (max_seq 4096), so each slot's table spans several
+    multi-page grid steps."""
     dt, Hq, Hkv, D = DTYPES[dtype], *ATTN_WIDTHS["qwen2"]
-    pool = ((2048, Hkv, 32, D), dt)
+    pool = ((28, 2048, Hkv, 32, D), dt)
     _compile(paged_decode, one_chip, ((32, 1, Hq, D), dt), pool, pool,
-             ((32, 128), jnp.int32), ((32,), jnp.int32))
+             ((32, 128), jnp.int32), ((32,), jnp.int32), ((), jnp.int32))
 
 
 @pytest.mark.parametrize("dtype", sorted(DTYPES))
@@ -113,3 +115,79 @@ def test_uts_expand_compiles_for_v5e(one_chip):
     u32 = jnp.uint32
     _compile(uts_expand, one_chip, ((1024,), u32), ((1024,), u32),
              ((1024,), jnp.int32), ((MAX_CHILD,), u32))
+
+
+# ------------------------------------------------ in-place pool updates
+# The serving engine's jitted calls at Qwen2-1.5B widths, cut to 2
+# layers: 8 slots, max_seq 2048 (64 blocks of 32), and a pool of 8 x
+# slots x 64 blocks, float32 as the engine keeps it.
+ENG_SLOTS, ENG_SEQ, ENG_BS = 8, 2048, 32
+ENG_BLOCKS = 8 * ENG_SLOTS * (ENG_SEQ // ENG_BS)
+
+
+def _engine_args(one_chip):
+    """(cfg, params, pool, row, i32, key): shapes only, placed on the
+    chip; ``row`` is the engine's prefill row cache. Weights are bf16 and
+    norm scales float32, as the benchmark loads them; the kernels are
+    forced on (the host's backend is the CPU)."""
+    import dataclasses
+
+    from repro.configs import ARCHS
+    from repro.models import init_lm, make_cache, make_paged_cache
+
+    cfg = dataclasses.replace(ARCHS["qwen2-1.5b"], n_layers=2,
+                              tie_embeddings=True, attn_impl="pallas",
+                              decode_impl="pallas")
+
+    def on_chip(a, dtype=None):
+        return jax.ShapeDtypeStruct(a.shape, dtype or a.dtype,
+                                    sharding=one_chip)
+
+    params = jax.tree_util.tree_map_with_path(
+        lambda path, a: on_chip(a, jnp.float32 if "ln" in
+                                jax.tree_util.keystr(path) else jnp.bfloat16),
+        jax.eval_shape(lambda: init_lm(jax.random.key(0), cfg)))
+    pool = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: make_paged_cache(cfg, ENG_BLOCKS, ENG_BS, ENG_SLOTS,
+                                 dtype=jnp.float32)))
+    row = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: make_cache(cfg, 1, ENG_SEQ, dtype=jnp.float32)))
+    key = on_chip(jax.eval_shape(lambda: jax.random.key(0)))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    return cfg, params, pool, row, i32, key
+
+
+def test_paged_decode_updates_the_pool_in_place(one_chip):
+    """The engine's decode call (8 positions over both layers) makes no
+    pool-sized buffer: its temporaries stay below one layer's K pool,
+    and the donated k and v pools alias its outputs."""
+    from repro.serve.engine import _make_decode_loop
+
+    cfg, params, pool, _, i32, key = _engine_args(one_chip)
+    decode = _make_decode_loop(cfg, ENG_SEQ, 8, 0.0)
+    mb = ENG_SEQ // ENG_BS
+    compiled = decode.lower(params, i32(ENG_SLOTS, 1), pool,
+                            i32(ENG_SLOTS, mb), i32(ENG_SLOTS),
+                            i32(ENG_SLOTS), key).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    pool_bytes = pool["k"].size * 4          # every layer's K pool
+    assert mem.temp_size_in_bytes < pool_bytes // cfg.n_layers
+    assert mem.alias_size_in_bytes >= 2 * pool_bytes
+
+
+def test_paged_prefill_aliases_the_pool(one_chip):
+    """The engine's admission (prefill into the row cache, scattered into
+    the pool) aliases the donated k and v pools to its outputs."""
+    from repro.serve.engine import _make_paged_fns
+
+    cfg, params, pool, row, i32, key = _engine_args(one_chip)
+    prefill, _ = _make_paged_fns(cfg, ENG_SEQ, ENG_BS, 0.0)
+    compiled = prefill.lower(params, i32(1, 512), pool,
+                             i32(ENG_SEQ // ENG_BS), i32(), row, i32(),
+                             key).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * pool["k"].size * 4
