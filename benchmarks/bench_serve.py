@@ -1,19 +1,18 @@
 """Serving engine throughput: tokens/s and host syncs per token for the
-legacy per-token decode loop vs the jitted multi-step ``lax.fori_loop``
-engine (on-device sampling, one host drain per N positions), plus the
-paged KV pool vs contiguous slots — same-workload tokens/s and max
-concurrent sequences at fixed cache memory (the paged packing win) —
-plus the PR 4 policy layer: the shared-system-prompt workload (radix
-prefix cache: hit rate and prefill tokens saved) and TTFT p50/p99 for
-short requests arriving behind long-prompt admissions, with and without
-chunked prefill — plus the PR 5 replica fabric: a skewed workload (one
+jitted multi-step ``lax.fori_loop`` decode over the paged KV pool
+(on-device sampling, one host drain per N positions), and max concurrent
+sequences at a fixed cache memory of SLOTS full-length rows (the paged
+packing win) — plus the PR 4 policy layer: the shared-system-prompt
+workload (radix prefix cache: hit rate and prefill tokens saved) and
+TTFT p50/p99 for short requests arriving behind long-prompt
+admissions, with and without chunked prefill — plus the PR 5 replica fabric: a skewed workload (one
 hot replica wedged on long RUNNING sequences, one cold) comparing
 queue-only stealing (the cold replica can only pick up sequences the hot
 one preempt-thrashes back to its queue, paying a chunked recompute
 prefill per move) against live KV migration (running sequences ship
 their written blocks at the first balance pass). Makespan in supersteps
 is the deterministic headline metric for that pair — plus the PR 6
-observability contract: the same fori_loop workload driven tracer-off
+observability contract: the same paged-loop workload driven tracer-off
 vs tracer-on (``serve_obs_overhead``: the disabled path is one
 attribute check, the on-path must stay within a few percent and add
 ZERO host syncs), registry-derived TTFT quantiles printed beside the
@@ -108,14 +107,14 @@ def _bench_cfg():
     )
 
 
-def _drive(engine, step_fn):
+def _drive(engine):
     for r in range(engine.max_slots):
         engine.submit(Request(rid=r, prompt=[3, r + 1, 4], max_new=MAX_NEW))
-    step_fn()  # admits every slot + compiles prefill/decode
+    engine.step()  # admits every slot + compiles prefill/decode
     toks0, syncs0 = engine.tokens_out, engine.host_syncs
     t0 = time.time()
     while engine.load > 0:
-        step_fn()
+        engine.step()
     dt = time.time() - t0
     toks = engine.tokens_out - toks0
     return toks / dt, (engine.host_syncs - syncs0) / max(toks, 1)
@@ -255,7 +254,7 @@ def _mk_skew_engines(cfg, params, tracer=None):
     retraces."""
     return [
         Engine(cfg, params, max_slots=SKEW_SLOTS, max_seq=MAX_SEQ,
-               pad_len=MAX_SEQ, steps_per_sync=STEPS_PER_SYNC, paged=True,
+               pad_len=MAX_SEQ, steps_per_sync=STEPS_PER_SYNC,
                block_size=PAGED_BS, num_blocks=SKEW_BLOCKS,
                prefill_chunk=SKEW_CHUNK,
                token_budget=SKEW_SLOTS * STEPS_PER_SYNC,
@@ -366,8 +365,8 @@ def _chaos_arm(cfg, params, faults=None):
     wall-clock gates hard."""
     engines = [
         Engine(cfg, params, max_slots=2, max_seq=MAX_SEQ, pad_len=8,
-               steps_per_sync=STEPS_PER_SYNC, paged=True,
-               block_size=PAGED_BS, num_blocks=32, replica_id=i)
+               steps_per_sync=STEPS_PER_SYNC, block_size=PAGED_BS,
+               num_blocks=32, replica_id=i)
         for i in range(CHAOS_REPLICAS)
     ]
     bal = GLBReplicaBalancer(engines, migrate=True, faults=faults)
@@ -386,32 +385,28 @@ def run():
     cfg = _bench_cfg()
     params = init_lm(jax.random.key(0), cfg)
 
-    tps_old, spt_old = _best_of(
-        lambda: Engine(cfg, params, max_slots=SLOTS, max_seq=MAX_SEQ,
-                       pad_len=8, steps_per_sync=1),
-        lambda e: _drive(e, e.step_legacy),
-    )
+    # The paged fori_loop engine at steady state: SLOTS sequences of
+    # MAX_SEQ rows' worth of pool blocks, one host drain per
+    # STEPS_PER_SYNC positions. One untimed run first: the process's
+    # first decode pays warm-up the traced arms below would not.
+    rows = SLOTS * MAX_SEQ
+    loop_kw = dict(max_slots=SLOTS, max_seq=MAX_SEQ, pad_len=8,
+                   steps_per_sync=STEPS_PER_SYNC, block_size=PAGED_BS,
+                   num_blocks=rows // PAGED_BS)
+    _drive(Engine(cfg, params, **loop_kw))
+    tps_pg, spt_pg = _best_of(lambda: Engine(cfg, params, **loop_kw),
+                              _drive)
 
-    tps_new, spt_new = _best_of(
-        lambda: Engine(cfg, params, max_slots=SLOTS, max_seq=MAX_SEQ,
-                       pad_len=8, steps_per_sync=STEPS_PER_SYNC),
-        lambda e: _drive(e, e.step),
-    )
-
-    # Observability overhead: the identical fori_loop workload with a
+    # Observability overhead: the identical paged-loop workload with a
     # LIVE Tracer (engine-step spans, load/pool counters, request
-    # lifecycle events, metrics observations). tracer-off IS tps_new —
+    # lifecycle events, metrics observations). tracer-off IS tps_pg —
     # the disabled path is one attribute check on NULL_TRACER. The
     # deterministic invariant is syncs/token: tracing must add ZERO
     # host syncs (events are host-side dict appends, never device
     # drains); tokens/s overhead gates advisorily in compare.py.
     tps_on, spt_on = _best_of(
-        lambda: Engine(cfg, params, max_slots=SLOTS, max_seq=MAX_SEQ,
-                       pad_len=8, steps_per_sync=STEPS_PER_SYNC,
-                       tracer=Tracer()),
-        lambda e: _drive(e, e.step),
-    )
-    obs_overhead = 100.0 * (1.0 - tps_on / max(tps_new, 1e-9))
+        lambda: Engine(cfg, params, tracer=Tracer(), **loop_kw), _drive)
+    obs_overhead = 100.0 * (1.0 - tps_on / max(tps_pg, 1e-9))
 
     # Flight-recorder overhead: the same workload tracing into a ring
     # bounded FAR below the run's event count (forced wraparound), i.e.
@@ -423,11 +418,9 @@ def run():
     def _mk_flight():
         fr = FlightRecorder(capacity=FLIGHT_CAPACITY)
         flights.append(fr)
-        return Engine(cfg, params, max_slots=SLOTS, max_seq=MAX_SEQ,
-                      pad_len=8, steps_per_sync=STEPS_PER_SYNC,
-                      tracer=fr)
+        return Engine(cfg, params, tracer=fr, **loop_kw)
 
-    tps_fl, spt_fl = _best_of(_mk_flight, lambda e: _drive(e, e.step))
+    tps_fl, spt_fl = _best_of(_mk_flight, _drive)
     flight = flights[-1]
     flight_valid = int(validate_chrome_trace(flight.dump()) == [])
     assert len(flight.events) <= FLIGHT_CAPACITY
@@ -436,32 +429,13 @@ def run():
         f"FLIGHT_CAPACITY (events={len(flight.events)})"
     )
 
-    # Paged pool, same workload and same KV rows as the contiguous engine:
-    # tokens/s should track the contiguous fast path (the pool adds a
-    # block-table walk, not extra attention work).
-    rows = SLOTS * MAX_SEQ
-    tps_pg, spt_pg = _best_of(
-        lambda: Engine(cfg, params, max_slots=SLOTS, max_seq=MAX_SEQ,
-                       pad_len=8, steps_per_sync=STEPS_PER_SYNC,
-                       paged=True, block_size=PAGED_BS,
-                       num_blocks=rows // PAGED_BS),
-        lambda e: _drive(e, e.step),
-    )
-
-    # Packing at fixed HBM: the contiguous engine reserves max_seq rows
-    # per slot, so `rows` of cache memory cap it at SLOTS concurrent
-    # sequences; the paged engine packs by actual length.
+    # Packing at fixed HBM: `rows` of cache memory would hold SLOTS
+    # full-length (max_seq) reservations; the paged engine packs by
+    # actual length.
     n_reqs = 3 * rows // (PAGED_BS + SHORT_MAX_NEW)
-    tps_pc, conc_c = _best_of(
-        lambda: Engine(cfg, params, max_slots=SLOTS, max_seq=MAX_SEQ,
-                       pad_len=8, steps_per_sync=STEPS_PER_SYNC),
-        lambda e: _drive_packing(e, n_reqs),
-    )
     tps_pp, conc_p = _best_of(
-        lambda: Engine(cfg, params, max_slots=rows // PAGED_BS,
-                       max_seq=MAX_SEQ, pad_len=8,
-                       steps_per_sync=STEPS_PER_SYNC, paged=True,
-                       block_size=PAGED_BS, num_blocks=rows // PAGED_BS),
+        lambda: Engine(cfg, params, **dict(loop_kw,
+                                           max_slots=rows // PAGED_BS)),
         lambda e: _drive_packing(e, n_reqs),
     )
 
@@ -469,7 +443,7 @@ def run():
     # off vs on. "saved" = fraction of prefill positions served from
     # cached blocks instead of recomputed.
     pool_kw = dict(max_slots=SLOTS, max_seq=MAX_SEQ, pad_len=PREFIX_PAD,
-                   steps_per_sync=STEPS_PER_SYNC, paged=True,
+                   steps_per_sync=STEPS_PER_SYNC,
                    block_size=PAGED_BS, num_blocks=rows // PAGED_BS)
     tps_nc, _ = _best_of(lambda: Engine(cfg, params, **pool_kw),
                          lambda e: _drive_prefix(e, N_PREFIX_REQS))
@@ -553,24 +527,16 @@ def run():
     greedy_identical = int(outs_cr == outs_cl)
 
     # syncs per decoded *position* is the architectural constant: the
-    # legacy loop drains every position (1.0), the fori_loop engine drains
-    # once per steps_per_sync positions.
+    # fori_loop engine drains once per steps_per_sync positions.
     return [
-        ("serve_legacy_loop", 1e6 / max(tps_old, 1e-9),
-         f"tok_s={tps_old:.1f};syncs_per_tok={spt_old:.3f};"
-         f"syncs_per_pos=1.000"),
-        ("serve_fori_loop", 1e6 / max(tps_new, 1e-9),
-         f"tok_s={tps_new:.1f};syncs_per_tok={spt_new:.3f};"
-         f"syncs_per_pos={1.0 / STEPS_PER_SYNC:.3f};"
-         f"speedup={tps_new / max(tps_old, 1e-9):.2f}x"),
         ("serve_obs_overhead", 1e6 / max(tps_on, 1e-9),
-         f"tok_s_on={tps_on:.1f};tok_s_off={tps_new:.1f};"
+         f"tok_s_on={tps_on:.1f};tok_s_off={tps_pg:.1f};"
          f"overhead_pct={obs_overhead:.1f};"
          f"syncs_per_tok_on={spt_on:.3f};"
-         f"syncs_per_tok_off={spt_new:.3f}"),
+         f"syncs_per_tok_off={spt_pg:.3f}"),
         ("serve_flight_overhead", 1e6 / max(tps_fl, 1e-9),
          f"tok_s={tps_fl:.1f};"
-         f"vs_untraced={tps_fl / max(tps_new, 1e-9):.2f}x;"
+         f"vs_untraced={tps_fl / max(tps_pg, 1e-9):.2f}x;"
          f"syncs_per_tok={spt_fl:.3f};"
          f"ring_capacity={FLIGHT_CAPACITY};"
          f"ring_events={len(flight.events)};"
@@ -578,15 +544,11 @@ def run():
          f"dump_valid={flight_valid}"),
         ("serve_paged_loop", 1e6 / max(tps_pg, 1e-9),
          f"tok_s={tps_pg:.1f};syncs_per_tok={spt_pg:.3f};"
-         f"vs_contiguous={tps_pg / max(tps_new, 1e-9):.2f}x;"
+         f"syncs_per_pos={1.0 / STEPS_PER_SYNC:.3f};"
          f"block_size={PAGED_BS}"),
-        ("serve_packing_contiguous", 1e6 / max(tps_pc, 1e-9),
-         f"tok_s={tps_pc:.1f};max_concurrent={conc_c};"
-         f"hbm_rows={rows}"),
         ("serve_packing_paged", 1e6 / max(tps_pp, 1e-9),
          f"tok_s={tps_pp:.1f};max_concurrent={conc_p};"
-         f"hbm_rows={rows};concurrency_gain="
-         f"{conc_p / max(conc_c, 1):.1f}x"),
+         f"hbm_rows={rows}"),
         ("serve_prefix_cache", 1e6 / max(tps_cache, 1e-9),
          f"tok_s={tps_cache:.1f};vs_no_cache="
          f"{tps_cache / max(tps_nc, 1e-9):.2f}x;"

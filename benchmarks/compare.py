@@ -52,7 +52,7 @@ class Gate:
 # ---------------------------------------------------------------- tracked
 GATES = [
     # --- serve: deterministic structure -------------------------------
-    Gate("serve", "serve_fori_loop", "syncs_per_tok", "lower", 0.01,
+    Gate("serve", "serve_paged_loop", "syncs_per_tok", "lower", 0.01,
          note="host syncs per token is the fast path's invariant"),
     Gate("serve", "serve_packing_paged", "max_concurrent", "higher", 0.0,
          note="paged packing at fixed HBM"),
@@ -116,10 +116,8 @@ GATES = [
          note="the crash must actually cost recovery work (else the "
               "scenario no longer exercises the ledger)"),
     # --- serve: wall-clock, loose + advisory --------------------------
-    Gate("serve", "serve_fori_loop", "tok_s", "higher", 0.60,
-         note="decode throughput cliff detector", hard=False),
     Gate("serve", "serve_paged_loop", "tok_s", "higher", 0.60,
-         hard=False),
+         note="decode throughput cliff detector", hard=False),
     # Tracer-on overhead vs the committed baseline: warn past +5 points
     # (wall-clock on a shared runner, so advisory — the contract itself
     # lives in the tracer-off row and the syncs gate above).
@@ -127,7 +125,7 @@ GATES = [
          abs_tol=5.0, note="tracer-on tokens/s cost (% points)",
          hard=False),
     Gate("serve", "serve_obs_overhead", "tok_s_off", "higher", 0.60,
-         note="tracer-off throughput must track serve_fori_loop",
+         note="tracer-off throughput must track serve_paged_loop",
          hard=False),
     Gate("serve", "serve_flight_overhead", "tok_s", "higher", 0.60,
          note="ring-buffer tracing throughput cliff detector",

@@ -9,9 +9,8 @@ machine-readable (CI uploads the kernels and serve suites per PR).
   bench_params           — §2.4: w/z/n tuning space
   bench_kernels          — Pallas kernels vs oracles + CPU timings
   bench_moe_glb          — GLB applied to MoE expert placement
-  bench_serve            — engine decode loop: tokens/s + host syncs/token,
-                           paged KV pool vs contiguous slots (throughput +
-                           max concurrency at fixed HBM)
+  bench_serve            — paged engine decode loop: tokens/s + host
+                           syncs/token, max concurrency at fixed HBM
 
 Each ``--json`` artifact carries a ``meta`` block — wall-clock start/end
 (unix), host name, jax version, and the observability layer's

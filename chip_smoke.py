@@ -15,7 +15,7 @@ non-zero:
 2. Compile cache: ``repro.launch.compile_cache``.
 3. Serving: TinyLlama-1.1B at its published widths (22 layers, d=2048,
    32/4 heads, vocab 32000), random weights from ``--seed``. Two
-   ``Engine(paged=True)`` replicas sit behind ``GLBReplicaBalancer``, the
+   ``Engine`` replicas sit behind ``GLBReplicaBalancer``, the
    way ``examples/serve_lm.py`` builds them. Eight requests of 192-256
    prompt tokens and 32-39 new tokens are all submitted to replica 0.
    The fabric must terminate with every request done.
@@ -112,7 +112,7 @@ def serve(cfg, params, reqs, engine_kw, replicas: int):
     returns the engines after GLB termination."""
     from repro.serve.engine import Engine, GLBReplicaBalancer
 
-    engines = [Engine(cfg, params, paged=True, replica_id=i, **engine_kw)
+    engines = [Engine(cfg, params, replica_id=i, **engine_kw)
                for i in range(replicas)]
     bal = GLBReplicaBalancer(engines)
     for r in reqs:

@@ -2,20 +2,19 @@
 request balancer (paper's library applied to serving). All requests land on
 replica 0; the balancer's lifeline matching redistributes them.
 
-    PYTHONPATH=src python examples/serve_lm.py            # contiguous slots
-    PYTHONPATH=src python examples/serve_lm.py --paged    # paged KV pool
-    PYTHONPATH=src python examples/serve_lm.py --paged --prefix-cache \
+    PYTHONPATH=src python examples/serve_lm.py            # paged KV pool
+    PYTHONPATH=src python examples/serve_lm.py --prefix-cache \
         --prefill-chunk 8                                 # radix cache +
                                                           # chunked prefill
-    PYTHONPATH=src python examples/serve_lm.py --paged --replicas 3 \
+    PYTHONPATH=src python examples/serve_lm.py --replicas 3 \
         --migrate                                         # live KV migration
-    PYTHONPATH=src python examples/serve_lm.py --paged --replicas 3 \
+    PYTHONPATH=src python examples/serve_lm.py --replicas 3 \
         --chaos                                           # crash a replica,
                                                           # recover losslessly
 
-With ``--paged`` each replica runs the block-granular KV pool + the
-continuous-batching scheduler (admission, watermark preemption) and the
-exit report includes pool occupancy/fragmentation. ``--prefix-cache``
+Each replica runs the block-granular KV pool + the continuous-batching
+scheduler (admission, watermark preemption) and the exit report
+includes pool occupancy/fragmentation. ``--prefix-cache``
 adds the radix prefix cache — requests here share a system prompt, so
 later admissions fork the cached blocks instead of re-prefilling them —
 and the report gains hit-rate / prefill-tokens-saved lines.
@@ -32,9 +31,9 @@ and prints the fabric-level merged stats report.
 overloaded replicas BEFORE anyone starves, with the reactive lifeline
 tiers as backstop; the exit report gains a predictive line (diffusion
 moves, predictions scored, mean |error|). ``--slo-admission`` (needs
-``--slo`` with a ``ttft_ms`` or ``queue_wait_ms`` target and
-``--paged``) makes each scheduler admit urgent-first by predicted SLO
-slack and pace relaxed admissions.
+``--slo`` with a ``ttft_ms`` or ``queue_wait_ms`` target) makes each
+scheduler admit urgent-first by predicted SLO slack and pace relaxed
+admissions.
 
 ``--trace PATH`` records the whole run — request lifecycle spans across
 replicas, engine steps, prefill chunks, steal/migration events — as
@@ -67,18 +66,16 @@ SYSTEM_PROMPT = [7, 3, 9, 2, 5, 8, 6, 4, 1, 2, 3, 4, 9, 9, 8, 7]
 
 def main():
     ap = argparse.ArgumentParser()
-    ap.add_argument("--paged", action="store_true",
-                    help="paged KV-cache pool + scheduler per replica")
     ap.add_argument("--prefix-cache", action="store_true",
-                    help="radix prefix cache (requires --paged)")
+                    help="radix prefix cache")
     ap.add_argument("--prefill-chunk", type=int, default=None,
-                    help="chunked prefill budget (requires --paged)")
+                    help="chunked prefill budget")
     ap.add_argument("--replicas", type=int, default=2,
                     help="number of engine replicas in the fabric")
     ap.add_argument("--migrate", action="store_true",
                     help="steal LIVE sequences (KV migration) when a "
                          "victim's queue is empty but its slots are "
-                         "saturated (requires --paged)")
+                         "saturated")
     ap.add_argument("--predictive", action="store_true",
                     help="cost-modeled diffusive balancing: move "
                          "predicted block-seconds off overloaded "
@@ -86,8 +83,8 @@ def main():
                          "(DESIGN.md §16)")
     ap.add_argument("--slo-admission", action="store_true",
                     help="SLO-aware admission ordering/pacing per "
-                         "replica (requires --paged and --slo with a "
-                         "ttft_ms or queue_wait_ms target)")
+                         "replica (requires --slo with a ttft_ms or "
+                         "queue_wait_ms target)")
     ap.add_argument("--trace", metavar="PATH", default=None,
                     help="write a Perfetto-loadable Chrome trace JSON "
                          "of the run to PATH")
@@ -108,22 +105,15 @@ def main():
                          "§15): the heartbeat detector fences it, "
                          "lifelines re-wire, and its requests are "
                          "re-admitted on the survivors with identical "
-                         "greedy tokens (requires --paged, >= 2 "
-                         "replicas)")
+                         "greedy tokens (requires >= 2 replicas)")
     args = ap.parse_args()
     enable_compile_cache()
 
     cfg = ARCHS["tinyllama-1.1b"].smoke()
     params = init_lm(jax.random.key(0), cfg)
-    kw = dict(max_slots=2, max_seq=64, pad_len=32, steps_per_sync=4)
-    if args.paged:
-        kw.update(paged=True, block_size=8,
-                  prefix_cache=args.prefix_cache,
-                  prefill_chunk=args.prefill_chunk)
-    elif args.prefix_cache or args.prefill_chunk or args.migrate \
-            or args.chaos:
-        ap.error("--prefix-cache / --prefill-chunk / --migrate / "
-                 "--chaos require --paged")
+    kw = dict(max_slots=2, max_seq=64, pad_len=32, steps_per_sync=4,
+              block_size=8, prefix_cache=args.prefix_cache,
+              prefill_chunk=args.prefill_chunk)
     if args.chaos and args.replicas < 2:
         ap.error("--chaos needs at least 2 replicas to survive")
     # ONE tracer for the whole fabric: request spans cross replicas.
@@ -136,9 +126,9 @@ def main():
         tracer = None
     slo = SLOMonitor(parse_slo_spec(args.slo)) if args.slo else None
     if args.slo_admission:
-        if not args.paged or slo is None:
-            ap.error("--slo-admission requires --paged and --slo with "
-                     "a ttft_ms or queue_wait_ms target")
+        if slo is None:
+            ap.error("--slo-admission requires --slo with a ttft_ms or "
+                     "queue_wait_ms target")
         kw.update(slo=slo, slo_admission=True)
     cost_model = None
     if args.predictive:
@@ -183,7 +173,7 @@ def main():
               f"{bal.readmitted_running} running request(s) re-admitted "
               f"on the survivors; zero requests lost")
     total = sum(e.tokens_out for e in engines)
-    mode = "paged" if args.paged else "contiguous"
+    mode = "paged"
     if args.prefix_cache:
         mode += "+prefix-cache"
     if args.prefill_chunk:
@@ -200,24 +190,23 @@ def main():
           f"in {dt:.1f}s over {args.replicas} replicas")
     for i, e in enumerate(engines):
         line = (f"  replica {i}: {e.tokens_out} tokens, {e.steps} steps, "
-                f"peak {e.peak_running} concurrent")
-        if args.paged:
-            line += (f", peak pool occupancy {e.peak_occupancy:.2f}, "
-                     f"peak fragmentation {e.peak_fragmentation:.2f}, "
-                     f"{e.sched.admissions} admissions, "
-                     f"{e.sched.preemptions} preemptions")
+                f"peak {e.peak_running} concurrent, "
+                f"peak pool occupancy {e.peak_occupancy:.2f}, "
+                f"peak fragmentation {e.peak_fragmentation:.2f}, "
+                f"{e.sched.admissions} admissions, "
+                f"{e.sched.preemptions} preemptions")
         if e.migrations_out or e.migrations_in:
             line += (f", {e.migrations_out} migrated out / "
                      f"{e.migrations_in} in")
         print(line)
-        if args.paged and e.prefix_cache is not None:
+        if e.prefix_cache is not None:
             c = e.prefix_cache
             print(f"    prefix cache: {c.hits} hits / {c.misses} misses "
                   f"(hit rate {c.hit_rate:.0%}), "
                   f"{c.tokens_reused} prefill tokens saved, "
                   f"{c.evictions} evictions, "
                   f"{e.pool.cached_blocks} blocks cached now")
-        if args.paged and e.sched.chunks_scheduled:
+        if e.sched.chunks_scheduled:
             print(f"    chunked prefill: {e.sched.chunks_scheduled} chunks")
     print()
     print(bal.report())

@@ -1,31 +1,27 @@
-"""Serving engine: continuous batching over decode slots + GLB request
-balancing across replicas.
+"""Serving engine: continuous batching over decode slots and a paged KV
+pool + GLB request balancing across replicas.
 
-Each replica owns a fixed pool of decode slots (static shapes). New
-requests prefill into a free slot (prompts padded to a bucket length,
-KV/conv state written into a reused preallocated row cache — no
-``make_cache`` allocation churn per admission); all active slots advance
+Each replica owns a fixed pool of decode slots (static shapes) and the
+paged KV subsystem (DESIGN.md §7): attention caches are flat block pools
+(``models.make_paged_cache``) mapped per sequence by ``serve.kvpool``,
+and every engine step is planned by the continuous-batching scheduler
+(``serve.scheduler`` — lookahead block reservation, watermark-based
+preempt-and-requeue of the youngest sequence, per-step token budget,
+strict-FIFO admission). An admitted request prefills into a reused
+preallocated row cache (prompts padded to a bucket length — no
+``make_cache`` allocation churn per admission) whose KV blocks are
+scattered into the pool. All active slots then advance
 ``steps_per_sync`` tokens per engine step inside ONE jitted
 ``lax.fori_loop`` decode: sampling (greedy or temperature, device-side
 PRNG key threading) happens on device, per-slot done masks gate cache
-writes and length/budget accounting, and each step emits an
-(N, slots) token buffer the host drains with a single device->host sync —
-~N× fewer host round-trips than the per-token loop (kept as
-``step_legacy`` for benchmarking). Per-slot cache lengths (-1 marks an
-idle slot: its cache/state is untouched) flow through to the split-KV
-flash-decode kernel.
-
-``paged=True`` swaps the fixed contiguous per-slot KV rows for the paged
-subsystem (DESIGN.md §7): attention caches become flat block pools
-(``models.make_paged_cache``) mapped per sequence by ``serve.kvpool``,
-every engine step is planned by the continuous-batching scheduler
-(``serve.scheduler`` — lookahead block reservation, watermark-based
-preempt-and-requeue of the youngest sequence, per-step token budget,
-strict-FIFO admission), and decode attention walks the block table
-through the paged flash-decode kernel (`ops.attention(...,
-block_tables=)`). A preempted request resumes by recomputing its cache
-from prompt + generated-so-far, so greedy outputs are token-identical to
-an uninterrupted run.
+writes and length/budget accounting, decode attention walks the block
+table through the paged decode kernel (``ops.attention(...,
+block_tables=)``), and each step emits an (N, slots) token buffer the
+host drains with a single device->host sync. Per-slot cache lengths (-1
+marks an idle slot: its cache/state is untouched) flow through to the
+kernel. A preempted request resumes by recomputing its cache from
+prompt + generated-so-far, so greedy outputs are token-identical to an
+uninterrupted run.
 
 ``prefix_cache=True`` adds the radix prefix cache (DESIGN.md §8): a
 finished sequence's full KV blocks stay in a radix tree over its tokens,
@@ -152,10 +148,8 @@ def _scrub_row(row):
 
 def _make_decode_loop(cfg: ModelConfig, max_seq: int, steps_per_sync: int,
                       temperature: float):
-    """The jitted fori_loop fast path, shared by the contiguous and paged
-    engines (``bt`` is the block table for paged caches, None for
-    contiguous — one recurrence, so the done-mask/budget rules can never
-    diverge between the two)."""
+    """The jitted fori_loop decode (``bt`` is the (slots, max_blocks)
+    block table of the paged pool)."""
     vocab = cfg.vocab
 
     @functools.partial(jax.jit, donate_argnames=("cache",))
@@ -191,36 +185,6 @@ def _make_decode_loop(cfg: ModelConfig, max_seq: int, steps_per_sync: int,
         return buf, cache, key
 
     return decode_tokens
-
-
-def _make_fns(cfg: ModelConfig, temperature: float):
-    vocab = cfg.vocab
-
-    @jax.jit
-    def prefill_into_slot(params, tokens, cache, slot, row, true_len, key):
-        logits, row, _ = forward(
-            params, cfg, tokens=tokens, cache=_scrub_row(row),
-            cache_len=jnp.int32(0), mode="prefill",
-        )
-        def put(c, r):
-            start = (0, slot) + (0,) * (c.ndim - 2)
-            return jax.lax.dynamic_update_slice(c, r.astype(c.dtype), start)
-        cache = jax.tree.map(put, cache, row)
-        first = sample_tokens(
-            logits[0, true_len - 1, ..., :vocab], key, temperature
-        )
-        return first, cache, row
-
-    @jax.jit
-    def decode_one(params, tokens, cache, lens):
-        # Pre-fast-path decode: one step, greedy, logits -> host argmax is
-        # the caller's job historically; argmax stays on device here but
-        # the loop still syncs every token (step_legacy baseline).
-        logits, cache = decode_step(params, cfg, tokens, cache, lens)
-        nxt = jnp.argmax(logits[:, 0, ..., :vocab], axis=-1)
-        return nxt.astype(jnp.int32), cache
-
-    return prefill_into_slot, decode_one
 
 
 def _make_paged_fns(cfg: ModelConfig, max_seq: int, block_size: int,
@@ -297,16 +261,17 @@ def _make_chunk_fn(cfg: ModelConfig, temperature: float):
 
 class Engine:
     """One serving replica: continuous batching over a fixed pool of
-    decode slots, jitted multi-token decode between host syncs, and —
-    with ``paged=True`` — the paged KV subsystem (block pool, scheduler,
-    radix prefix cache, chunked prefill, live migration). See the module
-    docstring for the architecture; a fabric of Engines is driven by
-    :class:`GLBReplicaBalancer`."""
+    decode slots, jitted multi-token decode between host syncs, and the
+    paged KV subsystem (block pool, scheduler, radix prefix cache,
+    chunked prefill, live migration). See the module docstring for the
+    architecture; a fabric of Engines is driven by
+    :class:`GLBReplicaBalancer`. ``paged`` is accepted for callers that
+    still pass ``paged=True``; ``paged=False`` is refused."""
 
     def __init__(self, cfg: ModelConfig, params, max_slots: int = 4,
                  max_seq: int = 256, pad_len: int = 32,
                  steps_per_sync: int = 8, temperature: float = 0.0,
-                 seed: int = 0, paged: bool = False,
+                 seed: int = 0, paged: bool = True,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
                  watermark_blocks: int = 0,
@@ -317,6 +282,11 @@ class Engine:
                  tracer=None, metrics=None, slo=None,
                  slo_admission: bool = False, cost_model=None,
                  replica_id: int = 0):
+        if not paged:
+            raise ValueError(
+                "the contiguous engine was removed: Engine always serves "
+                "from the paged KV pool (drop paged=False)"
+            )
         self.cfg = cfg
         self.params = params
         # Observability (DESIGN.md §10): tracer defaults to the no-op
@@ -337,8 +307,6 @@ class Engine:
         # Shared fabric-wide like the tracer/SLO monitor; None costs one
         # attribute check per request boundary.
         self.cost_model = cost_model
-        if slo_admission and not paged:
-            raise ValueError("slo_admission needs the paged scheduler")
         self.replica_id = replica_id
         if self.tracer.enabled:
             self.tracer.process_name(replica_id, f"replica {replica_id}")
@@ -347,8 +315,7 @@ class Engine:
         self.max_seq = max_seq
         self.pad_len = pad_len
         self.steps_per_sync = steps_per_sync
-        self.paged = paged
-        self.prefix_cache = None       # set below for paged engines
+        self.prefix_cache = None       # set below with prefix_cache=True
         self.queue: deque[Request] = deque()
         self.slots: List[Optional[Request]] = [None] * max_slots
         self.lens = np.full(max_slots, -1, np.int32)    # -1 => idle slot
@@ -360,7 +327,7 @@ class Engine:
         self.tokens_out = 0
         self.host_syncs = 0    # blocking device->host transfer points
         self.peak_running = 0  # max concurrent sequences observed
-        self.peak_occupancy = 0.0   # paged: max pool occupancy observed
+        self.peak_occupancy = 0.0   # max pool occupancy observed
         self.peak_fragmentation = 0.0
         self.migrations_out = 0     # live sequences shipped to a peer
         self.migrations_in = 0      # live sequences landed with their KV
@@ -370,68 +337,60 @@ class Engine:
         # Fresh first tokens synced this step: the step's end observes
         # how long each was held (first_token_hold_ms).
         self._first_tokens: List[Request] = []
-        if paged:
-            bs = block_size or paged_block_kv(max_seq, cfg.hd)
-            assert max_seq % bs == 0, (max_seq, bs)
-            self.block_size = bs
-            self.max_blocks = max_seq // bs
-            self.num_blocks = num_blocks or max_slots * self.max_blocks
-            assert self.num_blocks >= self.max_blocks, \
-                "pool must fit at least one full-length sequence"
-            self.pool = KVPool(self.num_blocks, bs)
-            if prefix_cache or prefill_chunk is not None:
-                # Recurrent conv/ssm state is not block-addressable: a
-                # cached prefix (or an earlier chunk) carries hidden
-                # state the pool cannot fork, so prefix reuse and
-                # chunked prefill are attention-family features.
-                assert cfg.family not in ("ssm", "hybrid"), (
-                    "prefix cache / chunked prefill need stateless "
-                    f"attention KV, not family={cfg.family!r}"
-                )
-            if prefix_cache:
-                self.prefix_cache = RadixPrefixCache(
-                    self.pool, tracer=self.tracer, pid=replica_id
-                )
-            self.sched = ContinuousBatchingScheduler(
-                self.pool, max_slots, lookahead=steps_per_sync,
-                max_seq=max_seq, watermark_blocks=watermark_blocks,
-                token_budget=token_budget, prefill_chunk=prefill_chunk,
-                cache=self.prefix_cache, shed_policy=shed_policy,
-                tracer=self.tracer, metrics=self.metrics, slo=self.slo,
-                slo_admission=slo_admission, cost_model=cost_model,
-                pid=replica_id,
+        bs = block_size or paged_block_kv(max_seq, cfg.hd)
+        assert max_seq % bs == 0, (max_seq, bs)
+        self.block_size = bs
+        self.max_blocks = max_seq // bs
+        self.num_blocks = num_blocks or max_slots * self.max_blocks
+        assert self.num_blocks >= self.max_blocks, \
+            "pool must fit at least one full-length sequence"
+        self.pool = KVPool(self.num_blocks, bs)
+        if prefix_cache or prefill_chunk is not None:
+            # Recurrent conv/ssm state is not block-addressable: a
+            # cached prefix (or an earlier chunk) carries hidden
+            # state the pool cannot fork, so prefix reuse and
+            # chunked prefill are attention-family features.
+            assert cfg.family not in ("ssm", "hybrid"), (
+                "prefix cache / chunked prefill need stateless "
+                f"attention KV, not family={cfg.family!r}"
             )
-            self.cache = make_paged_cache(
-                cfg, self.num_blocks, bs, max_slots, dtype=jnp.float32
+        if prefix_cache:
+            self.prefix_cache = RadixPrefixCache(
+                self.pool, tracer=self.tracer, pid=replica_id
             )
-            self._prefill_paged, self._copy_block = _make_paged_fns(
-                cfg, max_seq, bs, temperature
-            )
-            # How paged_decode walks a slot's table: it copies pages in
-            # the pool's dtype. None: no attention layer, no kernel.
-            self._decode_grid = (
-                (self.max_blocks, bs, cfg.hd, cfg.n_kv_heads,
-                 self.cache["k"].dtype)
-                if "k" in self.cache else None
-            )
-            self._prefill_chunk_fn = (
-                _make_chunk_fn(cfg, temperature)
-                if self.sched.chunked_mode else None
-            )
-        else:
-            assert not prefix_cache and prefill_chunk is None, \
-                "prefix cache / chunked prefill require paged=True"
-            self.cache = make_cache(cfg, max_slots, max_seq,
-                                    dtype=jnp.float32)
-            self._prefill, self._decode_1 = _make_fns(cfg, temperature)
-        # ONE decode recurrence for both cache layouts (bt=None contiguous)
+        self.sched = ContinuousBatchingScheduler(
+            self.pool, max_slots, lookahead=steps_per_sync,
+            max_seq=max_seq, watermark_blocks=watermark_blocks,
+            token_budget=token_budget, prefill_chunk=prefill_chunk,
+            cache=self.prefix_cache, shed_policy=shed_policy,
+            tracer=self.tracer, metrics=self.metrics, slo=self.slo,
+            slo_admission=slo_admission, cost_model=cost_model,
+            pid=replica_id,
+        )
+        self.cache = make_paged_cache(
+            cfg, self.num_blocks, bs, max_slots, dtype=jnp.float32
+        )
+        self._prefill_paged, self._copy_block = _make_paged_fns(
+            cfg, max_seq, bs, temperature
+        )
+        # How paged_decode walks a slot's table: it copies pages in
+        # the pool's dtype. None: no attention layer, no kernel.
+        self._decode_grid = (
+            (self.max_blocks, bs, cfg.hd, cfg.n_kv_heads,
+             self.cache["k"].dtype)
+            if "k" in self.cache else None
+        )
+        self._prefill_chunk_fn = (
+            _make_chunk_fn(cfg, temperature)
+            if self.sched.chunked_mode else None
+        )
         self._decode_n = _make_decode_loop(
             cfg, max_seq, steps_per_sync, temperature
         )
 
     def submit(self, req: Request):
         # An empty prompt has no position to sample a first token from:
-        # the legacy prefill would crash and a chunked admission would
+        # a single-shot prefill would crash and a chunked admission would
         # wedge its slot in a zero-token prefill — reject it loudly.
         if not req.prompt:
             raise ValueError(f"request {req.rid} has an empty prompt")
@@ -465,7 +424,7 @@ class Engine:
         cache already holds its prefix, which is exactly the signal the
         diffusive balancer wants."""
         cm = self.cost_model
-        bs = self.block_size if self.paged else self.max_seq
+        bs = self.block_size
         if queued:
             ptoks = self._prefix_tokens(req)
             cached = (self.prefix_cache.hit_length(ptoks)
@@ -495,72 +454,18 @@ class Engine:
     @property
     def pool_occupancy(self) -> float:
         """Memory-pressure signal for the replica balancer: fraction of
-        KV capacity in use (paged: live pool blocks; contiguous: busy
-        slots — each slot is a full max_seq reservation)."""
-        if self.paged:
-            return self.pool.occupancy
-        return 1.0 - self.free_slots / self.max_slots
+        KV capacity in use (live pool blocks)."""
+        return self.pool.occupancy
 
     def can_accept(self) -> bool:
         """Whether one more typical admission fits right now: a free slot
-        and, for paged caches, the scheduler's own admission predicate
-        for a prompt-bucket-sized request (one policy, no drift)."""
+        and the scheduler's own admission predicate for a
+        prompt-bucket-sized request (one policy, no drift)."""
         if self.free_slots == 0:
             return False
-        if not self.paged:
-            return True
         return self.sched.can_admit(
             self.pad_len, all(s is None for s in self.slots)
         )
-
-    def _admit(self):
-        for i in range(self.max_slots):
-            if self.slots[i] is None and self.queue:
-                req = self.queue.popleft()
-                t_adm = now_us()
-                if req.t_queued:
-                    wait_ms = (t_adm - req.t_queued) / 1e3
-                    self.metrics.histogram("queue_wait_ms").observe(
-                        wait_ms
-                    )
-                    if self.slo is not None:
-                        self.slo.observe("queue_wait_ms", wait_ms)
-                if self.tracer.enabled:
-                    self.tracer.req_phase(req.rid, "prefill",
-                                          pid=self.replica_id,
-                                          args={"slot": i})
-                    self.tracer.begin("prefill", pid=self.replica_id,
-                                      args={"rid": req.rid})
-                true_len = min(len(req.prompt), self.pad_len)
-                toks = np.zeros((1, self.pad_len), np.int32)
-                toks[0, :true_len] = req.prompt[:true_len]
-                self._key, sub = jax.random.split(self._key)
-                first, self.cache, self._row = self._prefill(
-                    self.params, jnp.asarray(toks), self.cache, i,
-                    self._row, true_len, sub,
-                )
-                first = int(first)          # one sync per admission
-                self.host_syncs += 1
-                req.out.append(first)
-                self.slots[i] = req
-                self.lens[i] = true_len
-                self.budget[i] = req.max_new
-                self.tokens[i, 0] = first
-                self.tokens_out += 1
-                req.t_first = now_us()
-                self._first_tokens.append(req)
-                self.metrics.histogram("prefill_chunk_ms").observe(
-                    (req.t_first - t_adm) / 1e3
-                )
-                if req.t_submit:
-                    ttft_ms = (req.t_first - req.t_submit) / 1e3
-                    self.metrics.histogram("ttft_ms").observe(ttft_ms)
-                    if self.slo is not None:
-                        self.slo.observe("ttft_ms", ttft_ms)
-                if self.tracer.enabled:
-                    self.tracer.end(pid=self.replica_id)
-                    self.tracer.req_phase(req.rid, "decode",
-                                          pid=self.replica_id)
 
     def _finish_check(self, i: int, req: Request):
         if (len(req.out) > req.max_new
@@ -593,7 +498,7 @@ class Engine:
             if self.tracer.enabled:
                 self.tracer.req_end(req.rid, pid=self.replica_id,
                                     args={"tokens": len(req.out)})
-            if self.paged and self.prefix_cache is not None:
+            if self.prefix_cache is not None:
                 # Thread the written prefix into the radix cache BEFORE
                 # freeing: the tree takes refs, free drops the seq's, and
                 # the cached blocks survive at refcount 1 (reclaimable).
@@ -605,9 +510,8 @@ class Engine:
             self.slots[i] = None
             self.lens[i] = -1
             self.budget[i] = 0
-            if self.paged:
-                self.sched.release(req.rid)
-                self.sched.slot_released(i)
+            self.sched.release(req.rid)
+            self.sched.slot_released(i)
 
     def _drain(self, buf: np.ndarray):
         """Extend per-request outputs from the (N, slots) token buffer and
@@ -617,7 +521,7 @@ class Engine:
         for i, req in enumerate(self.slots):
             if req is None:
                 continue
-            if self.paged and self.sched.mid_prefill(i):
+            if self.sched.mid_prefill(i):
                 continue
             toks = buf[:, i]
             toks = toks[toks >= 0]
@@ -628,11 +532,11 @@ class Engine:
             self.lens[i] += n
             self.budget[i] -= n
             self.tokens_out += n
-            if self.paged and n:
+            if n:
                 self.pool.advance(req.rid, int(self.lens[i]))
             self._finish_check(i, req)
 
-    # ------------------------------------------------------------ paged path
+    # ------------------------------------------------------------ admission
     def _prefix_tokens(self, req: Request) -> List[int]:
         """Tokens an admission must have in cache before decoding: the
         (bucket-truncated) prompt, plus all-but-the-last generated token
@@ -759,14 +663,11 @@ class Engine:
         the recompute resume prefills the full prefix). The balancer
         checks this before shedding so an incompatible thief is never
         handed a Migration it cannot land."""
-        return self.paged and written < self.max_seq
+        return written < self.max_seq
 
     def migratable_slots(self) -> List[int]:
         """Slots the balancer may shed, best victim first (the
-        scheduler's shed policy). Empty for contiguous engines — they
-        have no block-granular extract — and excludes mid-prefill slots."""
-        if not self.paged:
-            return []
+        scheduler's shed policy), excluding mid-prefill slots."""
         return self.sched.shed_candidates(self.slots, self.budget)
 
     def migrate_out(self, slot: int) -> Migration:
@@ -779,7 +680,6 @@ class Engine:
         token at position ``written``, just like a preemption resume.
         Mid-prefill slots are rejected: their KV is half-written and
         their chunk plan cannot move."""
-        assert self.paged, "live migration needs the paged KV pool"
         req = self.slots[slot]
         assert req is not None, f"slot {slot} is idle"
         if self.sched.mid_prefill(slot):
@@ -874,7 +774,6 @@ class Engine:
 
         Every path preserves greedy token identity — they differ only in
         how much prefill work the move costs."""
-        assert self.paged, "live migration needs the paged KV pool"
         req = mig.req
         if not self.can_host(mig.written):
             # Requeueing here would wedge/crash a later admission (the
@@ -968,7 +867,7 @@ class Engine:
         traced = self.tracer.enabled
         if traced:
             self.tracer.begin("decode", pid=self.replica_id)
-        bt = self._device_tables() if self.paged else None
+        bt = self._device_tables()
         buf, self.cache, self._key = self._decode_n(
             self.params, jnp.asarray(self.tokens), self.cache, bt,
             jnp.asarray(lens), jnp.asarray(budget), self._key,
@@ -978,7 +877,7 @@ class Engine:
             self.tracer.begin("drain", pid=self.replica_id)
         buf = np.asarray(buf)               # the single drain
         self.host_syncs += 1
-        if self.paged and self._decode_grid:
+        if self._decode_grid:
             self._count_kv_steps(lens, buf >= 0)
         self._drain(buf)
         if traced:
@@ -1048,8 +947,8 @@ class Engine:
     def step(self):
         """One engine iteration: admit, then `steps_per_sync` batched
         decode steps on device with ONE host drain at the end (idle slots
-        carry lens=-1 and stay untouched). Paged engines delegate
-        admission/preemption to the continuous-batching scheduler.
+        carry lens=-1 and stay untouched). The continuous-batching
+        scheduler plans admission and preemption.
         Traced runs wrap the iteration in an ``engine_step`` span and
         emit load/pool counter tracks; the untraced path dispatches
         straight to the implementation (one attribute check). The step's
@@ -1057,11 +956,11 @@ class Engine:
         token's hold, from its sync to here, goes to the always-on
         ``first_token_hold_ms`` histogram."""
         if not self.tracer.enabled:
-            self._step_impl()
+            self._step_paged()
         else:
             with self.tracer.span("engine_step", pid=self.replica_id,
                                   args={"step": self.steps}):
-                self._step_impl()
+                self._step_paged()
                 self.tracer.counter(
                     "load",
                     {"running": float(sum(s is not None
@@ -1069,59 +968,20 @@ class Engine:
                      "queued": float(len(self.queue))},
                     pid=self.replica_id,
                 )
-                if self.paged:
-                    ps = self.pool.stats()
-                    self.tracer.counter(
-                        "pool",
-                        {"occupancy_pct": round(100 * ps.occupancy, 2),
-                         "available_blocks":
-                             float(self.pool.available_blocks),
-                         "watermark": float(self.sched.watermark)},
-                        pid=self.replica_id,
-                    )
+                ps = self.pool.stats()
+                self.tracer.counter(
+                    "pool",
+                    {"occupancy_pct": round(100 * ps.occupancy, 2),
+                     "available_blocks": float(self.pool.available_blocks),
+                     "watermark": float(self.sched.watermark)},
+                    pid=self.replica_id,
+                )
         if self._first_tokens:
             t_end = now_us()
             hold = self.metrics.histogram("first_token_hold_ms")
             for req in self._first_tokens:
                 hold.observe((t_end - req.t_first) / 1e3)
             self._first_tokens.clear()
-
-    def _step_impl(self):
-        if self.paged:
-            return self._step_paged()
-        self._admit()
-        if all(s is None for s in self.slots):
-            return
-        self.peak_running = max(
-            self.peak_running, sum(s is not None for s in self.slots)
-        )
-        self._decode_and_drain(self.lens, self.budget)
-        self.steps += 1
-
-    def step_legacy(self):
-        """The pre-fast-path loop: ONE decode step and one host round-trip
-        per token. Kept as the bench_serve / equivalence baseline."""
-        assert not self.paged, "step_legacy is the contiguous baseline"
-        self._admit()
-        if all(s is None for s in self.slots):
-            return
-        nxt, self.cache = self._decode_1(
-            self.params, jnp.asarray(self.tokens), self.cache,
-            jnp.asarray(self.lens),
-        )
-        nxt = np.asarray(nxt)
-        self.host_syncs += 1
-        for i, req in enumerate(self.slots):
-            if req is None:
-                continue
-            tok = int(nxt[i])
-            req.out.append(tok)
-            self.tokens[i, 0] = tok
-            self.lens[i] += 1
-            self.budget[i] -= 1
-            self.tokens_out += 1
-            self._finish_check(i, req)
-        self.steps += 1
 
     def stats(self) -> dict:
         """Per-replica counters for fabric-level result collection
@@ -1146,18 +1006,15 @@ class Engine:
             migrations_in=self.migrations_in,
             migrations_seeded=self.migrations_seeded,
             migrations_recompute=self.migrations_recompute,
+            admissions=self.sched.admissions,
+            preemptions=self.sched.preemptions,
+            adoptions=self.sched.adoptions,
+            chunks_scheduled=self.sched.chunks_scheduled,
+            peak_occupancy_pct=round(100 * self.peak_occupancy, 1),
+            paged_decode_pages_per_step=(
+                paged_decode_grid(*self._decode_grid)[0]
+                if self._decode_grid else 0),
         )
-        if self.paged:
-            sync.update(
-                admissions=self.sched.admissions,
-                preemptions=self.sched.preemptions,
-                adoptions=self.sched.adoptions,
-                chunks_scheduled=self.sched.chunks_scheduled,
-                peak_occupancy_pct=round(100 * self.peak_occupancy, 1),
-                paged_decode_pages_per_step=(
-                    paged_decode_grid(*self._decode_grid)[0]
-                    if self._decode_grid else 0),
-            )
         if self.prefix_cache is not None:
             pc = self.prefix_cache
             sync.update(
@@ -1187,7 +1044,7 @@ class GLBReplicaBalancer:
 
     * **tier 1 — queued requests**: drained from the victim's queue
       oldest-first (FIFO), preserving arrival order;
-    * **tier 2 — live sequences** (``migrate=True``, paged engines): when
+    * **tier 2 — live sequences** (``migrate=True``): when
       the victim's queue is empty but its slots are saturated, the
       victim's shed policy picks running sequences and their KV state
       migrates block-for-block (``Engine.migrate_out`` →
@@ -1196,10 +1053,10 @@ class GLBReplicaBalancer:
       victim always keeps at least one running sequence (a bare handoff
       helps nobody).
 
-    Hungry = "can admit more work right now": a free decode slot AND (for
-    paged engines) free KV blocks above the watermark, with an empty local
-    queue — so a replica under memory pressure never steals, and a busy
-    replica with spare capacity does.
+    Hungry = "can admit more work right now": a free decode slot AND free
+    KV blocks above the watermark, with an empty local queue — so a
+    replica under memory pressure never steals, and a busy replica with
+    spare capacity does.
 
     Termination is GLB-style: the load vector gathered for the matching
     doubles as the termination detector (``core.lifeline.terminated`` —
@@ -1273,8 +1130,7 @@ class GLBReplicaBalancer:
             for e in engines:
                 if e.slo is None:
                     e.slo = slo
-                    if e.paged:
-                        e.sched.slo = slo
+                    e.sched.slo = slo
         P = len(engines)
         z = params.resolve_z(P)
         self._buddies = jnp.asarray(lifeline_buddies(P, z))
@@ -1350,7 +1206,7 @@ class GLBReplicaBalancer:
         q = len(e.queue)
         if q:
             return q
-        if self.migrate and e.paged and e.free_slots == 0:
+        if self.migrate and e.free_slots == 0:
             cands = [s for s in e.migratable_slots()
                      if any(t.can_host(int(e.lens[s])) for t in thieves
                             if t is not e)]
@@ -1485,8 +1341,8 @@ class GLBReplicaBalancer:
                           "rid": best.rid,
                           "cost": round(best_c, 3)})
         if (moved == 0 and costs[d] > hi and self.migrate
-                and donor.paged and recip.paged and recip.free_slots > 0
-                and donor.free_slots == 0 and not donor.queue):
+                and recip.free_slots > 0 and donor.free_slots == 0
+                and not donor.queue):
             cands = [s for s in donor.migratable_slots()
                      if recip.can_host(int(donor.lens[s]))]
             if len(cands) > 1:          # the donor keeps one running
@@ -1634,15 +1490,15 @@ class GLBReplicaBalancer:
     def _readmit_running(self, req: Request, dead: int) -> None:
         target = None
         for j, e in enumerate(self.engines):
-            if not self._alive[j] or not e.paged:
+            if not self._alive[j]:
                 continue
             if e.can_host(len(e._prefix_tokens(req))):
                 target = j
                 break
         if target is None:
             # The non-survivable case (DESIGN.md §15): a running
-            # sequence needs a paged survivor whose max_seq fits the
-            # recompute prefix. Contiguous engines have no resume path.
+            # sequence needs a survivor whose max_seq fits the
+            # recompute prefix.
             raise RuntimeError(
                 f"request {req.rid} ({len(req.out)} tokens in) lost with "
                 f"replica {dead}: no surviving paged replica can host "
@@ -1766,7 +1622,7 @@ class GLBReplicaBalancer:
                               "thief": self.engines[thief].replica_id,
                               "n": took},
                     )
-            elif self.migrate and v.paged and self.engines[thief].paged:
+            elif self.migrate:
                 self._steal_live(self.engines[thief], v)
         self._step += 1
         return False

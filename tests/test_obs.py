@@ -233,7 +233,7 @@ def test_preemption_resume_span_ordering():
     queued -> prefill -> decode -> preempted -> queued -> ... -> resumed
     -> decode -> end, and the full trace must validate."""
     tr = Tracer()
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=5,
+    e = Engine(CFG, PARAMS, block_size=8, num_blocks=5,
                tracer=tr, **KW)
     reqs = [Request(rid=i, prompt=[3, i + 1, 4, 2], max_new=14 + i % 4)
             for i in range(5)]
@@ -272,9 +272,9 @@ def test_migration_span_ownership_across_replicas():
     chain valid across both pids."""
     tr = Tracer()
     kw = dict(max_slots=1, max_seq=64, pad_len=16, steps_per_sync=4)
-    victim = Engine(CFG, PARAMS, paged=True, block_size=8, tracer=tr,
+    victim = Engine(CFG, PARAMS, block_size=8, tracer=tr,
                     replica_id=0, **kw)
-    thief = Engine(CFG, PARAMS, paged=True, block_size=8, tracer=tr,
+    thief = Engine(CFG, PARAMS, block_size=8, tracer=tr,
                    replica_id=1, **kw)
     req = Request(rid=0, prompt=list(PROMPT16), max_new=30)
     victim.submit(req)
@@ -319,7 +319,7 @@ def test_nulltracer_and_tracer_token_identity():
     NullTracer default), and fully traced runs of the same workload emit
     identical greedy tokens."""
     def outs(tracer):
-        e = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=5,
+        e = Engine(CFG, PARAMS, block_size=8, num_blocks=5,
                    prefix_cache=True, prefill_chunk=4, tracer=tracer,
                    **KW)
         return _drive(e, [Request(rid=i, prompt=[3, i + 1, 4, 2],
@@ -361,23 +361,22 @@ def test_first_token_hold_once_per_fresh_admission_never_on_resume():
     kw = dict(max_slots=2, max_seq=32, pad_len=8, steps_per_sync=8)
     reqs = [Request(rid=i, prompt=[3, i + 1, 4, 2], max_new=14 + i % 4)
             for i in range(5)]
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=5, **kw)
+    e = Engine(CFG, PARAMS, block_size=8, num_blocks=5, **kw)
     _drive(e, reqs)
     assert e.sched.preemptions > 0, "pool sizing must force preemption"
     hold = e.metrics.histogram("first_token_hold_ms")
     assert hold.count == len(reqs) == \
         e.sched.admissions - e.sched.preemptions
     assert hold.vmin >= 0.0
-    # The contiguous engine's admissions observe it the same way.
+    # Admissions with no preemption observe it once each.
     c = Engine(CFG, PARAMS, **kw)
     _drive(c, [Request(rid=i, prompt=[3, i + 1], max_new=5)
                for i in range(3)])
     assert c.metrics.histogram("first_token_hold_ms").count == 3
 
 
-@pytest.mark.parametrize("paged", [True, False])
-def test_backlogged_decode_counters_count_only_calls_with_a_queue(paged):
-    e = Engine(CFG, PARAMS, paged=paged, **KW)
+def test_backlogged_decode_counters_count_only_calls_with_a_queue():
+    e = Engine(CFG, PARAMS, **KW)
     seen = []
     decode = e._decode_n
 
@@ -403,7 +402,7 @@ def test_paged_decode_kv_step_counters_follow_slot_lengths():
     16-wide heads walk one block of one kv head a step."""
     from repro.kernels.paged_decode import paged_decode_grid
 
-    e = Engine(CFG, PARAMS, paged=True, **KW)
+    e = Engine(CFG, PARAMS, **KW)
     P, steps = paged_decode_grid(*e._decode_grid)
     assert (P, steps) == (1, CFG.n_kv_heads * e.max_blocks)
     seen = []
@@ -443,7 +442,7 @@ def test_paged_decode_kv_steps_live_multi_step_table_and_idle():
     from repro.kernels.paged_decode import paged_decode_grid
 
     cfg = dataclasses.replace(CFG, head_dim=128)
-    e = Engine(cfg, init_lm(jax.random.key(1), cfg), paged=True,
+    e = Engine(cfg, init_lm(jax.random.key(1), cfg),
                max_slots=4, max_seq=2048, pad_len=8, steps_per_sync=8,
                block_size=8)
     P, steps = paged_decode_grid(*e._decode_grid)
@@ -475,7 +474,7 @@ def test_stats_is_registry_view_and_merge_superset():
     superset of every per-replica snapshot's keys (the satellite
     regression: no more hand-rolled drift between the three report
     sites)."""
-    engines = [Engine(CFG, PARAMS, paged=True, block_size=8,
+    engines = [Engine(CFG, PARAMS, block_size=8,
                       prefix_cache=True, replica_id=i, **KW)
                for i in range(2)]
     bal = GLBReplicaBalancer(engines, migrate=True)

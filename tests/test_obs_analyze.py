@@ -308,7 +308,7 @@ def test_analyzer_real_engine_preemption():
     attributes >=99% of every request's wall-clock with a nonzero
     preempted bucket."""
     tr = Tracer()
-    eng = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=5,
+    eng = Engine(CFG, PARAMS, block_size=8, num_blocks=5,
                  max_slots=2, max_seq=32, pad_len=8, steps_per_sync=8,
                  tracer=tr)
     reqs = [Request(rid=i, prompt=[3, i + 1, 4, 2], max_new=14 + i % 4)
@@ -344,9 +344,9 @@ def test_analyzer_real_engine_migration_stitch():
     decode time (steal-efficiency numerator)."""
     tr = Tracer()
     kw = dict(max_slots=1, max_seq=64, pad_len=16, steps_per_sync=4)
-    victim = Engine(CFG, PARAMS, paged=True, block_size=8, tracer=tr,
+    victim = Engine(CFG, PARAMS, block_size=8, tracer=tr,
                     replica_id=0, **kw)
-    thief = Engine(CFG, PARAMS, paged=True, block_size=8, tracer=tr,
+    thief = Engine(CFG, PARAMS, block_size=8, tracer=tr,
                    replica_id=1, **kw)
     req = Request(rid=0, prompt=list(PROMPT16), max_new=30)
     victim.submit(req)
@@ -378,7 +378,7 @@ def test_analyzer_fabric_steal_efficiency():
     """Balancer-driven fabric: steal instants inside superstep spans
     count as steal rounds; tier-1 moves come from the instants' n."""
     tr = Tracer()
-    engines = [Engine(CFG, PARAMS, paged=True, block_size=8, max_slots=2,
+    engines = [Engine(CFG, PARAMS, block_size=8, max_slots=2,
                       max_seq=32, pad_len=8, steps_per_sync=4, tracer=tr,
                       replica_id=i) for i in range(2)]
     bal = GLBReplicaBalancer(engines, migrate=True, tracer=tr)
@@ -530,7 +530,7 @@ def test_slo_engine_integration():
     from repro.core import fabric_summary
     slo = SLOMonitor([SLOTarget("ttft_ms", 0.001),    # unmeetable
                       SLOTarget("tpot_ms", 1e6)])     # unmissable
-    engines = [Engine(CFG, PARAMS, paged=True, block_size=8, max_slots=2,
+    engines = [Engine(CFG, PARAMS, block_size=8, max_slots=2,
                       max_seq=32, pad_len=8, steps_per_sync=4,
                       replica_id=i) for i in range(2)]
     bal = GLBReplicaBalancer(engines, slo=slo)

@@ -18,7 +18,7 @@ from repro.serve.engine import Engine, GLBReplicaBalancer, Request
 
 CFG = ARCHS["tinyllama-1.1b"].smoke()
 PARAMS = init_lm(jax.random.key(0), CFG)
-KW = dict(max_slots=2, max_seq=32, pad_len=8, steps_per_sync=4, paged=True)
+KW = dict(max_slots=2, max_seq=32, pad_len=8, steps_per_sync=4)
 
 
 def _serve(tracer, n=3):

@@ -1,11 +1,12 @@
 """Chunked-prefill edge cases: chunk boundaries vs block boundaries,
 chunk budgets smaller than one block, decode-only steps between two
 chunks of the same request, and preemption of a half-prefilled sequence
-— all while greedy outputs stay token-identical to the contiguous
-engine (chunking is scheduling, never semantics)."""
+— all while greedy outputs stay token-identical to the plain greedy
+reference (chunking is scheduling, never semantics)."""
 import jax
 import numpy as np
 
+from _serve_reference import greedy_reference
 from repro.configs import ARCHS
 from repro.models import init_lm
 from repro.serve.engine import Engine, Request
@@ -34,15 +35,20 @@ def _run(engine, reqs, per_step=None):
     return [list(r.out) for r in reqs]
 
 
+def _reference(reqs, kw):
+    return greedy_reference(CFG, PARAMS, reqs, pad_len=kw["pad_len"],
+                            max_seq=kw["max_seq"])
+
+
 KW = dict(max_slots=2, max_seq=64, pad_len=32, steps_per_sync=8)
-BASE = _run(Engine(CFG, PARAMS, **KW), _reqs())
+BASE = _reference(_reqs(), KW)
 
 
 def test_chunk_on_block_boundary():
     """prefill_chunk == block_size: every chunk ends exactly where a
     block ends, so chunk scatters never straddle and the next chunk
     starts a fresh block."""
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, prefill_chunk=8,
+    e = Engine(CFG, PARAMS, block_size=8, prefill_chunk=8,
                **KW)
     assert _run(e, _reqs()) == BASE
     assert e.sched.chunks_scheduled >= 3 * (21 // 8)
@@ -53,7 +59,7 @@ def test_chunk_smaller_than_block():
     block (the paged_prefill q_offset path mid-block), including a
     1-token chunk budget."""
     for chunk in (3, 1):
-        e = Engine(CFG, PARAMS, paged=True, block_size=8,
+        e = Engine(CFG, PARAMS, block_size=8,
                    prefill_chunk=chunk, **KW)
         assert _run(e, _reqs(2)) == BASE[:2], f"chunk={chunk}"
         assert e.sched.chunks_scheduled >= 2 * (21 // max(chunk, 1))
@@ -62,7 +68,7 @@ def test_chunk_smaller_than_block():
 def test_chunk_larger_than_block_unaligned():
     """Chunk spans multiple blocks and ends mid-block (21-token prefix,
     5-token chunks over 8-token blocks: boundaries at 5/10/15/20)."""
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, prefill_chunk=5,
+    e = Engine(CFG, PARAMS, block_size=8, prefill_chunk=5,
                **KW)
     assert _run(e, _reqs()) == BASE
 
@@ -74,10 +80,9 @@ def test_decode_only_step_between_chunks():
     identical tokens."""
     short = Request(rid=0, prompt=LONG[:4], max_new=4)
     long_ = Request(rid=1, prompt=LONG + [30], max_new=8)
-    e_c = Engine(CFG, PARAMS, **KW)
-    base = _run(e_c, [Request(rid=0, prompt=LONG[:4], max_new=4),
-                      Request(rid=1, prompt=LONG + [30], max_new=8)])
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, prefill_chunk=6,
+    base = _reference([Request(rid=0, prompt=LONG[:4], max_new=4),
+                       Request(rid=1, prompt=LONG + [30], max_new=8)], KW)
+    e = Engine(CFG, PARAMS, block_size=8, prefill_chunk=6,
                token_budget=KW["steps_per_sync"], **KW)
     progress, decoded = [], []
 
@@ -106,15 +111,15 @@ def test_preempt_half_prefilled_sequence():
     """A pool too small for the older sequence's growth plus a younger
     admission's prefill: the younger one is preempted MID-PREFILL
     (watermark, youngest-first), requeued, and restarted from scratch —
-    outputs stay identical to the contiguous engine."""
+    outputs stay identical to the reference."""
     kw = dict(max_slots=2, max_seq=32, pad_len=32, steps_per_sync=8)
     mk = lambda: [Request(rid=0, prompt=LONG[:4], max_new=20),
                   Request(rid=1, prompt=LONG + [30], max_new=6)]
-    base = _run(Engine(CFG, PARAMS, **kw), mk())
+    base = _reference(mk(), kw)
     # 6 blocks: both admissions fit (2 + 4 blocks with lookahead), then
     # the older sequence's decode growth hits an empty free list and must
     # preempt the youngest — which is still chunking its 21-token prefix.
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=6,
+    e = Engine(CFG, PARAMS, block_size=8, num_blocks=6,
                prefill_chunk=4, **kw)
     reqs = mk()
     trace = []          # (preemptions so far, victim's output length)
@@ -142,8 +147,8 @@ def test_empty_prompt_rejected_loudly():
     it up front on every engine flavor."""
     import pytest
 
-    for kw in (dict(), dict(paged=True, block_size=8),
-               dict(paged=True, block_size=8, prefill_chunk=4)):
+    for kw in (dict(), dict(block_size=8),
+               dict(block_size=8, prefill_chunk=4)):
         e = Engine(CFG, PARAMS, max_slots=1, max_seq=32, pad_len=8,
                    steps_per_sync=4, **kw)
         with pytest.raises(ValueError, match="empty prompt"):
@@ -157,7 +162,7 @@ def test_chunked_budget_bounds_prefill_work():
     however long the admission (prefill_chunk deliberately set far above
     the budget so the budget is the binding clamp)."""
     budget = 8
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, prefill_chunk=64,
+    e = Engine(CFG, PARAMS, block_size=8, prefill_chunk=64,
                token_budget=budget, **KW)
     per_step = {}
     orig = e._run_prefill_chunk

@@ -120,21 +120,19 @@ def test_diffusion_pairs_deterministic_and_balanced():
 def _slo_engine(**kw):
     slo = SLOMonitor(parse_slo_spec(kw.pop("spec", "ttft_ms=1000000")))
     return Engine(CFG, PARAMS, max_slots=kw.pop("max_slots", 2),
-                  max_seq=64, pad_len=8, steps_per_sync=4, paged=True,
+                  max_seq=64, pad_len=8, steps_per_sync=4,
                   block_size=8, num_blocks=24, slo=slo,
                   slo_admission=True, **kw), slo
 
 
 def test_slo_admission_requires_monitor_and_target():
     with pytest.raises(ValueError):
-        Engine(CFG, PARAMS, paged=True, block_size=8, max_seq=64,
+        Engine(CFG, PARAMS, block_size=8, max_seq=64,
                slo_admission=True)                       # no monitor
     with pytest.raises(ValueError):
         slo = SLOMonitor(parse_slo_spec("tpot_ms=50"))   # wrong metric
-        Engine(CFG, PARAMS, paged=True, block_size=8, max_seq=64,
+        Engine(CFG, PARAMS, block_size=8, max_seq=64,
                slo=slo, slo_admission=True)
-    with pytest.raises(ValueError):
-        Engine(CFG, PARAMS, slo_admission=True)          # not paged
 
 
 def test_slo_admission_orders_by_slack():
@@ -174,7 +172,7 @@ def test_slo_admission_paces_relaxed_tail():
 def test_fifo_admission_unchanged_without_flag():
     """Reactive-parity at the scheduler level: no flag, strict FIFO."""
     eng = Engine(CFG, PARAMS, max_slots=1, max_seq=64, pad_len=8,
-                 steps_per_sync=4, paged=True, block_size=8,
+                 steps_per_sync=4, block_size=8,
                  num_blocks=24)
     reqs = [Request(rid=i, prompt=[1 + i] * 4, max_new=20)
             for i in range(3)]
@@ -188,7 +186,7 @@ def test_fifo_admission_unchanged_without_flag():
 # --------------------------------------------- skewed-fabric scenarios
 def _skew_fabric(cost_model=None, predictive=False, n=6):
     engines = [Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=16,
-                      steps_per_sync=4, paged=True, block_size=8,
+                      steps_per_sync=4, block_size=8,
                       num_blocks=16, prefix_cache=True, prefill_chunk=8,
                       cost_model=cost_model)
                for _ in range(2)]
@@ -234,7 +232,7 @@ def test_predictive_moves_before_starvation():
 
 
 def test_predictive_requires_cost_model():
-    engines = [Engine(CFG, PARAMS, max_slots=2, max_seq=64, paged=True,
+    engines = [Engine(CFG, PARAMS, max_slots=2, max_seq=64,
                       block_size=8)]
     with pytest.raises(ValueError):
         GLBReplicaBalancer(engines, predictive=True)
@@ -255,7 +253,7 @@ def test_request_cost_credits_prefix_cache():
     """The same queued request is cheaper on a replica whose radix cache
     already holds its prefix."""
     eng = Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=16,
-                 steps_per_sync=4, paged=True, block_size=8,
+                 steps_per_sync=4, block_size=8,
                  num_blocks=24, prefix_cache=True, prefill_chunk=8,
                  cost_model=CostModel())
     shared = [5] * 16

@@ -1,10 +1,12 @@
 """Host-sync-free engine steps: the jitted fori_loop multi-step decode must
-emit exactly what the legacy per-token loop emits (greedy), honor per-slot
+emit exactly what the plain greedy reference emits, honor per-slot
 budgets and cache-length caps, thread PRNG keys deterministically for
-temperature sampling, and cut host drains by ~steps_per_sync."""
+temperature sampling, and cut host drains by ~steps_per_sync. The engine
+refuses the removed contiguous cache."""
 import jax
-import numpy as np
+import pytest
 
+from _serve_reference import greedy_reference
 from repro.configs import ARCHS
 from repro.models import init_lm
 from repro.serve.engine import Engine, Request
@@ -30,17 +32,28 @@ def _run(engine, reqs, step):
 
 
 def test_multi_step_matches_legacy_greedy():
-    """N-token fori_loop decode must produce token-identical outputs to the
-    per-token loop (same prefill, same greedy argmax, same cache math)."""
-    e_old = Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=8,
-                   steps_per_sync=1)
-    out_old = _run(e_old, _reqs(), e_old.step_legacy)
-    e_new = Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=8,
-                   steps_per_sync=8)
-    out_new = _run(e_new, _reqs(), e_new.step)
-    assert out_old == out_new
+    """The fori_loop decode at one token per sync and at eight must both
+    produce token-identical outputs to the plain greedy reference (same
+    prefill, same greedy argmax, same cache limits)."""
+    ref = greedy_reference(CFG, PARAMS, _reqs(), pad_len=8, max_seq=64)
+    for n in (1, 8):
+        e = Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=8,
+                   steps_per_sync=n)
+        assert _run(e, _reqs(), e.step) == ref, n
     # budget contract: prefill token + exactly max_new decode tokens
-    assert all(len(o) == 11 for o in out_new)
+    assert all(len(o) == 11 for o in ref)
+
+
+def test_engine_rejects_contiguous():
+    """The contiguous cache path is gone: asking for it fails loudly, and
+    the default engine is the paged one."""
+    with pytest.raises(ValueError, match="contiguous engine was removed"):
+        Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=8,
+               paged=False)
+    e = Engine(CFG, PARAMS, max_slots=2, max_seq=64, pad_len=8)
+    assert e.pool.num_blocks == 2 * e.max_blocks
+    assert set(e.cache) == {"k", "v"}
+    assert e.cache["k"].shape[1] == e.pool.num_blocks
 
 
 def test_host_syncs_reduced_by_steps_per_sync():

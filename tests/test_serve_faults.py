@@ -47,7 +47,7 @@ KW = dict(max_slots=2, max_seq=64, pad_len=16, steps_per_sync=4)
 
 
 def _fabric(n=3, faults=None, tracer=None, heartbeat_misses=None, **over):
-    kw = dict(paged=True, block_size=8, num_blocks=64, **KW)
+    kw = dict(block_size=8, num_blocks=64, **KW)
     kw.update(over)
     engines = [Engine(CFG, _params(), replica_id=i, tracer=tracer, **kw)
                for i in range(n)]
@@ -232,13 +232,15 @@ def test_zombie_is_fenced_after_declaration():
 
 
 def test_running_readmission_needs_compatible_host():
-    """A running casualty can only recompute-land on a paged survivor
-    with headroom; a fabric whose only survivor can't host must fail
-    loudly, not drop the request."""
+    """A running casualty can only recompute-land on a survivor whose
+    max_seq fits its recompute prefix; a fabric whose only survivor can't
+    host must fail loudly, not drop the request."""
     tr = None
-    victim = Engine(CFG, _params(), replica_id=0, paged=True, block_size=8,
+    victim = Engine(CFG, _params(), replica_id=0, block_size=8,
                     num_blocks=64, tracer=tr, **KW)
-    survivor = Engine(CFG, _params(), replica_id=1, tracer=tr, **KW)  # legacy
+    # max_seq 16 holds no prefix of the 16-token prompt plus its output
+    survivor = Engine(CFG, _params(), replica_id=1, block_size=8,
+                      tracer=tr, **dict(KW, max_seq=16))
     bal = GLBReplicaBalancer([victim, survivor], migrate=True,
                              faults=FaultInjector().crash(0, at=2))
     req = Request(rid=0, prompt=list(PROMPT16), max_new=40)
@@ -262,10 +264,10 @@ def test_incompatible_thief_no_sterile_steal():
     a victim whose sequences exceed every thief's max_seq produces NO
     match at all, not a sterile one (pre-fix: matched every round,
     moved nothing, moves counter still climbed)."""
-    victim = Engine(CFG, _params(), replica_id=0, paged=True, block_size=8,
+    victim = Engine(CFG, _params(), replica_id=0, block_size=8,
                     num_blocks=64, max_slots=2, max_seq=64, pad_len=16,
                     steps_per_sync=4)
-    thief = Engine(CFG, _params(), replica_id=1, paged=True, block_size=8,
+    thief = Engine(CFG, _params(), replica_id=1, block_size=8,
                    num_blocks=64, max_slots=2, max_seq=32, pad_len=16,
                    steps_per_sync=4)
     bal = GLBReplicaBalancer([victim, thief], migrate=True)

@@ -1,10 +1,13 @@
 """Cross-implementation identity matrix: under greedy sampling every
-serving engine — legacy per-token loop, contiguous fori_loop fast path,
-paged, paged + prefix cache (with and without chunked prefill) — must
-emit exactly the same tokens, across several registry architectures
-(dense, dense+qkv-bias, MoE — not just the one cfg earlier PRs pinned),
-including a forced-eviction run where a cached prefix is reclaimed under
-pool pressure and transparently recomputed.
+serving engine variant — paged, paged + prefix cache (with and without
+chunked prefill), a live migration mid-decode — must emit exactly the
+tokens of the plain greedy reference (``tests/_serve_reference.py``: one
+request at a time through ``models.forward`` / ``decode_step``, no
+engine), across several registry architectures (dense, dense+qkv-bias,
+MoE — not just the one cfg earlier PRs pinned), including a
+forced-eviction run where a cached prefix is reclaimed under pool
+pressure and transparently recomputed. The reference itself is checked
+against a cache-free forward over each emitted prefix.
 
 The MoE arch runs with a raised capacity_factor (dropless): with
 capacity-bounded dispatch a token's output depends on which OTHER slots
@@ -14,10 +17,12 @@ sort-dispatch, and paged-attention stack are still fully exercised."""
 import dataclasses
 
 import jax
+import jax.numpy as jnp
 import pytest
 
+from _serve_reference import greedy_reference
 from repro.configs import ARCHS
-from repro.models import init_lm
+from repro.models import forward, init_lm
 from repro.serve.engine import Engine, Request
 
 # dense / dense+qkv_bias / MoE — three distinct attention+ffn stacks
@@ -46,49 +51,75 @@ def _reqs(n=4, max_new=8):
     ]
 
 
-def _run(engine, reqs, step=None):
-    step = step or engine.step
+def _run(engine, reqs):
     for r in reqs:
         engine.submit(r)
     guard = 0
     while engine.load > 0 and guard < 600:
-        step()
+        engine.step()
         guard += 1
     assert all(r.done for r in reqs)
     return [list(r.out) for r in reqs]
 
 
 KW = dict(max_slots=2, max_seq=64, pad_len=16, steps_per_sync=4)
+# float32 logits of the smoke models: a tie broken differently by two
+# summation orders differs by a few ulps of a logit of order 1.
+LOGIT_TOL = 1e-5
+
+
+def _reference(cfg, params, reqs, kw=KW):
+    return greedy_reference(cfg, params, reqs, pad_len=kw["pad_len"],
+                            max_seq=kw["max_seq"])
 
 
 @pytest.fixture(scope="module", params=MATRIX_ARCHS)
 def baseline(request):
     cfg, params = _setup(request.param)
-    e = Engine(cfg, params, **KW)
-    return request.param, _run(e, _reqs(), e.step_legacy)
+    return request.param, _reference(cfg, params, _reqs())
 
 
-def test_contiguous_fast_matches_legacy(baseline):
+@pytest.mark.parametrize("arch", MATRIX_ARCHS)
+def test_greedy_reference_agrees_with_cache_free_forward(arch):
+    """Each token the reference emits is the best next token of a full
+    forward with no cache over the (truncated) prompt and the tokens
+    emitted before it: its logit is within LOGIT_TOL of that forward's
+    best logit, the rule by which the chip benchmark judges a served
+    token (``max_logit_gap``). This checks the reference's own prefill,
+    cache writes and decode positions."""
+    cfg, params = _setup(arch)
+    reqs = _reqs()
+    outs = _reference(cfg, params, reqs)
+    fwd = jax.jit(lambda p, t: forward(p, cfg, tokens=t)[0])
+    checked = 0
+    for req, out in zip(reqs, outs):
+        assert len(out) == req.max_new + 1
+        seq = list(req.prompt[:KW["pad_len"]]) + out
+        logits = fwd(params, jnp.asarray([seq], jnp.int32))[0]
+        logits = logits[..., :cfg.vocab]
+        for k, tok in enumerate(out):
+            row = logits[len(seq) - len(out) - 1 + k]
+            gap = float(row.max() - row[tok])
+            assert 0.0 <= gap <= LOGIT_TOL, (req.rid, k, gap)
+            checked += 1
+    assert checked == sum(r.max_new + 1 for r in reqs)
+
+
+def test_paged_matches_reference(baseline):
     arch, base = baseline
     cfg, params = _setup(arch)
-    assert _run(Engine(cfg, params, **KW), _reqs()) == base
-
-
-def test_paged_matches_legacy(baseline):
-    arch, base = baseline
-    cfg, params = _setup(arch)
-    e = Engine(cfg, params, paged=True, block_size=8, **KW)
+    e = Engine(cfg, params, block_size=8, **KW)
     assert _run(e, _reqs()) == base
     assert e.pool.free_blocks == e.pool.num_blocks
 
 
-def test_paged_prefix_cache_matches_legacy(baseline):
+def test_paged_prefix_cache_matches_reference(baseline):
     """Hit + miss paths: the first wave misses and seeds the radix tree,
     the second wave hits the shared prompt's cached blocks — and both
-    waves' outputs are token-identical to the legacy engine."""
+    waves' outputs are token-identical to the reference."""
     arch, base = baseline
     cfg, params = _setup(arch)
-    e = Engine(cfg, params, paged=True, block_size=8, prefix_cache=True,
+    e = Engine(cfg, params, block_size=8, prefix_cache=True,
                **KW)
     assert _run(e, _reqs()) == base
     assert e.prefix_cache.misses > 0
@@ -104,24 +135,24 @@ def test_paged_prefix_cache_matches_legacy(baseline):
             == e.pool.num_blocks)
 
 
-def test_paged_prefix_cache_chunked_matches_legacy(baseline):
+def test_paged_prefix_cache_chunked_matches_reference(baseline):
     arch, base = baseline
     cfg, params = _setup(arch)
-    e = Engine(cfg, params, paged=True, block_size=8, prefix_cache=True,
+    e = Engine(cfg, params, block_size=8, prefix_cache=True,
                prefill_chunk=4, token_budget=8, **KW)
     assert _run(e, _reqs()) == base
     assert e.sched.chunks_scheduled >= len(_reqs())
 
 
-def test_forced_live_migration_matches_legacy(baseline):
+def test_forced_live_migration_matches_reference(baseline):
     """A sequence yanked mid-decode from one replica and re-materialized
     block-for-block on another (Engine.migrate_out -> migrate_in) must
     emit exactly the tokens an uninterrupted single-engine run does —
     across the same arch matrix as every other engine variant."""
     arch, base = baseline
     cfg, params = _setup(arch)
-    e0 = Engine(cfg, params, paged=True, block_size=8, **KW)
-    e1 = Engine(cfg, params, paged=True, block_size=8, **KW)
+    e0 = Engine(cfg, params, block_size=8, **KW)
+    e1 = Engine(cfg, params, block_size=8, **KW)
     reqs = _reqs()
     for r in reqs:
         e0.submit(r)
@@ -152,11 +183,11 @@ def test_partial_hit_that_cannot_fit_falls_back_to_miss():
     pb = SHARED[:10] + [90, 91]                  # mid-block divergence
     mk = lambda: [Request(rid=0, prompt=list(pa), max_new=5),
                   Request(rid=1, prompt=list(pb), max_new=5)]
-    base = _run(Engine(cfg, params, **kw), mk())
+    base = _reference(cfg, params, mk(), kw)
     # 4 blocks total: A's release caches 2 blocks; B's hit-credited
     # admission needs 3 fresh blocks but pinning the 2 matched blocks
     # leaves only 2 available — the credited path cannot fit.
-    e = Engine(cfg, params, paged=True, block_size=8, num_blocks=4,
+    e = Engine(cfg, params, block_size=8, num_blocks=4,
                prefix_cache=True, **kw)
     reqs = mk()
     out = _run(e, reqs)                          # must not livelock
@@ -175,9 +206,8 @@ def test_forced_eviction_recomputes_transparently(baseline):
     arch, base = baseline
     cfg, params = _setup(arch)
     kw = dict(KW, max_slots=1, max_seq=32)
-    eb = Engine(cfg, params, **kw)
-    base3 = _run(eb, _reqs(3), eb.step_legacy)
-    e = Engine(cfg, params, paged=True, block_size=8, num_blocks=4,
+    base3 = _reference(cfg, params, _reqs(3), kw)
+    e = Engine(cfg, params, block_size=8, num_blocks=4,
                prefix_cache=True, **kw)
     assert _run(e, _reqs(3)) == base3
     assert e.prefix_cache.evictions > 0, \

@@ -11,6 +11,7 @@ result collection."""
 import jax
 import pytest
 
+from _serve_reference import greedy_reference
 from repro.configs import ARCHS
 from repro.core import merge_place_stats, terminated
 from repro.serve.engine import Engine, GLBReplicaBalancer, Request
@@ -31,16 +32,9 @@ PROMPT16 = [7, 3, 9, 2, 5, 8, 6, 4, 1, 2, 3, 4, 9, 9, 8, 7]
 KW = dict(max_slots=2, max_seq=64, pad_len=16, steps_per_sync=4)
 
 
-def _legacy_baseline(reqs):
-    e = Engine(CFG, _params(), **KW)
-    for r in reqs:
-        e.submit(r)
-    guard = 0
-    while e.load > 0 and guard < 600:
-        e.step_legacy()
-        guard += 1
-    assert all(r.done for r in reqs)
-    return [list(r.out) for r in reqs]
+def _reference_baseline(reqs):
+    return greedy_reference(CFG, _params(), reqs, pad_len=KW["pad_len"],
+                            max_seq=KW["max_seq"])
 
 
 def _drain(*engines, guard=600):
@@ -74,7 +68,7 @@ def test_extract_inject_roundtrip_pool_level():
 def test_mid_prefill_slot_cannot_migrate():
     """A half-prefilled slot owns half-written blocks and a chunk plan;
     it is excluded from shed_candidates and migrate_out rejects it."""
-    e = Engine(CFG, _params(), paged=True, block_size=8, prefill_chunk=4,
+    e = Engine(CFG, _params(), block_size=8, prefill_chunk=4,
                token_budget=4, **KW)
     e.submit(Request(rid=0, prompt=list(PROMPT16), max_new=5))
     e.step()                                # first chunk only (budget 4)
@@ -93,7 +87,7 @@ def _wedged_victim(steps=7, max_new=30):
     """One long-running sequence mid-decode on a paged engine: after
     ``steps`` bursts of 4 its written length is 16 + 4*steps (44 by
     default — 6 pool blocks)."""
-    e = Engine(CFG, _params(), paged=True, block_size=8,
+    e = Engine(CFG, _params(), block_size=8,
                **dict(KW, max_slots=1))
     req = Request(rid=0, prompt=list(PROMPT16), max_new=max_new)
     e.submit(req)
@@ -109,7 +103,7 @@ def _tight_thief(**extra):
     reserving another block — so the pool is tight when the migrant
     arrives, but nothing later forces an eviction of seeded blocks, and
     the pool drains naturally for the resume admission."""
-    e = Engine(CFG, _params(), paged=True, block_size=8, num_blocks=8,
+    e = Engine(CFG, _params(), block_size=8, num_blocks=8,
                **dict(KW, max_slots=2), **extra)
     blocker = Request(rid=50, prompt=list(BLOCKER_PROMPT), max_new=8)
     e.submit(blocker)
@@ -120,7 +114,7 @@ def _tight_thief(**extra):
 
 
 def test_pool_exhausted_falls_back_to_recompute():
-    base = _legacy_baseline([Request(rid=0, prompt=list(PROMPT16),
+    base = _reference_baseline([Request(rid=0, prompt=list(PROMPT16),
                                      max_new=30),
                              Request(rid=50, prompt=list(BLOCKER_PROMPT),
                                      max_new=8)])
@@ -140,7 +134,7 @@ def test_radix_seeded_resume():
     """When the whole sequence cannot fit, the thief plants however many
     full blocks DO fit in its radix cache, and the recompute admission
     hits the planted prefix instead of re-prefilling from scratch."""
-    base = _legacy_baseline([Request(rid=0, prompt=list(PROMPT16),
+    base = _reference_baseline([Request(rid=0, prompt=list(PROMPT16),
                                      max_new=30),
                              Request(rid=50, prompt=list(BLOCKER_PROMPT),
                                      max_new=8)])
@@ -163,10 +157,10 @@ def test_radix_seeded_resume():
 def test_migration_between_block_size_mismatch_recomputes():
     """Different pool geometries cannot exchange raw blocks; the move
     degrades to resume-by-recompute, never to corruption."""
-    base = _legacy_baseline([Request(rid=0, prompt=list(PROMPT16),
+    base = _reference_baseline([Request(rid=0, prompt=list(PROMPT16),
                                      max_new=30)])
     victim, req = _wedged_victim()
-    thief = Engine(CFG, _params(), paged=True, block_size=16, **KW)
+    thief = Engine(CFG, _params(), block_size=16, **KW)
     mode = thief.migrate_in(victim.migrate_out(0))
     assert mode == "recompute"
     _drain(victim, thief)
@@ -180,7 +174,7 @@ def test_migration_longer_than_thief_capacity_is_refused():
     refuses outright — ownership stays with the caller — and the
     balancer's can_host pre-filter never sheds to such a thief."""
     victim, req = _wedged_victim()          # written 44
-    thief = Engine(CFG, _params(), paged=True, block_size=8,
+    thief = Engine(CFG, _params(), block_size=8,
                    **dict(KW, max_seq=32))  # can host < 32 cache tokens
     assert not thief.can_host(44)
     mig = victim.migrate_out(0)
@@ -195,8 +189,8 @@ def test_migration_longer_than_thief_capacity_is_refused():
 def test_balancer_skips_incompatible_thief():
     """_steal_live's can_host filter: a saturated victim facing a thief
     with a smaller max_seq keeps its sequences instead of crashing."""
-    victim = Engine(CFG, _params(), paged=True, block_size=8, **KW)
-    thief = Engine(CFG, _params(), paged=True, block_size=8,
+    victim = Engine(CFG, _params(), block_size=8, **KW)
+    thief = Engine(CFG, _params(), block_size=8,
                    **dict(KW, max_seq=32))
     bal = GLBReplicaBalancer([victim, thief], migrate=True)
     reqs = [Request(rid=i, prompt=list(PROMPT16), max_new=40)
@@ -214,7 +208,7 @@ def test_balancer_skips_incompatible_thief():
 # ----------------------------------------------------------- shed policy
 def test_shed_policy_orders_candidates():
     def mk(policy):
-        e = Engine(CFG, _params(), paged=True, block_size=8,
+        e = Engine(CFG, _params(), block_size=8,
                    shed_policy=policy, **KW)
         e.submit(Request(rid=0, prompt=list(PROMPT16), max_new=20))
         e.submit(Request(rid=1, prompt=list(PROMPT16), max_new=6))
@@ -227,7 +221,7 @@ def test_shed_policy_orders_candidates():
     # rid 0 has far more budget left => budget policy leads with slot 0
     assert budget.migratable_slots()[0] == 0
     with pytest.raises(AssertionError):
-        Engine(CFG, _params(), paged=True, block_size=8,
+        Engine(CFG, _params(), block_size=8,
                shed_policy="bogus", **KW)
     _drain(young, budget)
 
@@ -236,7 +230,7 @@ def test_shed_policy_orders_candidates():
 def test_balancer_steals_queue_before_live_sequences():
     """A victim with queued requests sheds its queue (tier 1); live
     sequences move only when the queue is empty."""
-    mk = lambda: Engine(CFG, _params(), paged=True, block_size=8,
+    mk = lambda: Engine(CFG, _params(), block_size=8,
                         **dict(KW, max_slots=1))
     engines = [mk(), mk()]
     bal = GLBReplicaBalancer(engines, migrate=True)
@@ -249,7 +243,7 @@ def test_balancer_steals_queue_before_live_sequences():
 
 
 def test_balancer_saturated_victim_sheds_live_sequence():
-    mk = lambda: Engine(CFG, _params(), paged=True, block_size=8, **KW)
+    mk = lambda: Engine(CFG, _params(), block_size=8, **KW)
     engines = [mk(), mk()]
     bal = GLBReplicaBalancer(engines, migrate=True)
     reqs = [Request(rid=i, prompt=[3, i + 1, 4], max_new=20)
@@ -265,7 +259,7 @@ def test_balancer_saturated_victim_sheds_live_sequence():
 
 
 def test_balancer_migrate_off_never_moves_live():
-    mk = lambda: Engine(CFG, _params(), paged=True, block_size=8, **KW)
+    mk = lambda: Engine(CFG, _params(), block_size=8, **KW)
     engines = [mk(), mk()]
     bal = GLBReplicaBalancer(engines)   # migrate defaults off
     reqs = [Request(rid=i, prompt=[3, i + 1, 4], max_new=20)
@@ -281,7 +275,7 @@ def test_balancer_migrate_off_never_moves_live():
 # ------------------------------------- termination + result collection
 def test_termination_via_size_vector_and_result_collection():
     assert terminated([0, 0, 0]) and not terminated([0, 2, 0])
-    mk = lambda: Engine(CFG, _params(), paged=True, block_size=8, **KW)
+    mk = lambda: Engine(CFG, _params(), block_size=8, **KW)
     engines = [mk(), mk(), mk()]
     bal = GLBReplicaBalancer(engines, migrate=True)
     reqs = [Request(rid=i, prompt=[3, i + 1, 4], max_new=6 + i % 4)

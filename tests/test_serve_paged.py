@@ -1,12 +1,12 @@
-"""Paged serving contract: the paged engine (block pool + scheduler +
-paged decode) must emit exactly what the contiguous engine emits under
-greedy sampling — across mixed per-slot lengths, forced preemption and
-resume, and per-step token budgets — while packing more sequences into
-the same cache memory. Plus the balancer satellites: FIFO steals and
-capacity-based hunger."""
+"""Paged serving contract: the engine (block pool + scheduler + paged
+decode) must emit exactly what the plain greedy reference emits — across
+mixed per-slot lengths, forced preemption and resume, and per-step token
+budgets — while packing more sequences into a fixed KV memory than
+full-length rows would hold. Plus the balancer satellites: FIFO steals
+and capacity-based hunger."""
 import jax
-import numpy as np
 
+from _serve_reference import greedy_reference
 from repro.configs import ARCHS
 from repro.models import init_lm
 from repro.serve.engine import Engine, GLBReplicaBalancer, Request
@@ -32,15 +32,17 @@ def _run(engine, reqs):
     return [list(r.out) for r in reqs]
 
 
-def _contiguous_baseline(reqs_fn, **kw):
-    e = Engine(CFG, PARAMS, **kw)
-    return _run(e, reqs_fn())
+def _reference(reqs_fn, **kw):
+    return greedy_reference(CFG, PARAMS, reqs_fn(), pad_len=kw["pad_len"],
+                            max_seq=kw["max_seq"])
 
 
 def test_paged_matches_contiguous_greedy():
+    """Against the contiguous-cache greedy reference (one request at a
+    time, no engine)."""
     kw = dict(max_slots=2, max_seq=64, pad_len=8, steps_per_sync=8)
-    out_c = _contiguous_baseline(_reqs, **kw)
-    e_p = Engine(CFG, PARAMS, paged=True, **kw)
+    out_c = _reference(_reqs, **kw)
+    e_p = Engine(CFG, PARAMS, **kw)
     out_p = _run(e_p, _reqs())
     assert out_c == out_p
     # everything released: pool drains to empty
@@ -51,10 +53,10 @@ def test_paged_matches_contiguous_greedy():
 def test_paged_preempt_and_resume_token_identical():
     """A pool too small for both sequences' growth forces watermark
     preemption; resume-by-recompute must keep greedy outputs identical
-    to the never-preempted contiguous run."""
+    to the never-preempted reference run."""
     kw = dict(max_slots=2, max_seq=32, pad_len=8, steps_per_sync=8)
-    out_c = _contiguous_baseline(lambda: _reqs(5, 14), **kw)
-    e_t = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=5, **kw)
+    out_c = _reference(lambda: _reqs(5, 14), **kw)
+    e_t = Engine(CFG, PARAMS, block_size=8, num_blocks=5, **kw)
     out_t = _run(e_t, _reqs(5, 14))
     assert e_t.sched.preemptions > 0, "pool sizing must force preemption"
     assert out_t == out_c
@@ -66,12 +68,11 @@ def test_watermark_starved_pool_stays_live():
     watermark must keep decoding via partial reservations (it must never
     preempt itself into a permanent admit/preempt loop)."""
     kw = dict(max_slots=2, max_seq=64, pad_len=8, steps_per_sync=8)
-    req_c = Request(rid=0, prompt=[3, 1, 4, 2], max_new=60)
-    e_c = Engine(CFG, PARAMS, **kw)
-    out_c = _run(e_c, [req_c])
+    out_c = _reference(
+        lambda: [Request(rid=0, prompt=[3, 1, 4, 2], max_new=60)], **kw)
     # pool of exactly max_blocks, watermark 1: full lookahead reservation
     # is impossible near max_seq.
-    e_p = Engine(CFG, PARAMS, paged=True, block_size=8, num_blocks=8,
+    e_p = Engine(CFG, PARAMS, block_size=8, num_blocks=8,
                  watermark_blocks=1, **kw)
     req_p = Request(rid=0, prompt=[3, 1, 4, 2], max_new=60)
     out_p = _run(e_p, [req_p])
@@ -82,35 +83,32 @@ def test_paged_token_budget_paces_slots():
     """token_budget < slots * steps_per_sync pauses the youngest slots
     each step without changing any sequence's tokens."""
     kw = dict(max_slots=2, max_seq=32, pad_len=8, steps_per_sync=8)
-    out_c = _contiguous_baseline(lambda: _reqs(4, 12), **kw)
-    e_b = Engine(CFG, PARAMS, paged=True, token_budget=8, **kw)
+    out_c = _reference(lambda: _reqs(4, 12), **kw)
+    e_b = Engine(CFG, PARAMS, token_budget=8, **kw)
     out_b = _run(e_b, _reqs(4, 12))
     assert out_b == out_c
 
 
 def test_paged_packs_more_sequences_at_fixed_memory():
-    """With the same number of KV rows, the paged engine runs more
-    sequences concurrently than the contiguous engine has slots."""
-    max_seq, rows = 64, 4 * 64            # contiguous: 4 slots x 64 rows
-    e_c = Engine(CFG, PARAMS, max_slots=4, max_seq=max_seq, pad_len=8,
-                 steps_per_sync=4)
-    reqs_c = _reqs(12, 8)
-    _run(e_c, reqs_c)
-    assert e_c.peak_running == 4
+    """With KV memory for 4 full-length (max_seq) rows, the paged engine
+    runs at least twice as many sequences concurrently as 4 full-length
+    reservations would allow."""
+    max_seq, rows = 64, 4 * 64            # 4 full-length rows of KV
     bs = 8
     e_p = Engine(CFG, PARAMS, max_slots=rows // bs, max_seq=max_seq,
-                 pad_len=8, steps_per_sync=4, paged=True, block_size=bs,
-                 num_blocks=rows // bs)   # same rows of KV memory
+                 pad_len=8, steps_per_sync=4, block_size=bs,
+                 num_blocks=rows // bs)   # the same rows, as blocks
     reqs_p = _reqs(12, 8)
     _run(e_p, reqs_p)
-    assert e_p.peak_running >= 2 * e_c.peak_running
+    assert e_p.peak_running >= 2 * (rows // max_seq)
     # and the tokens are still identical per request
-    assert [r.out for r in reqs_p] == [r.out for r in reqs_c]
+    want = _reference(lambda: _reqs(12, 8), max_seq=max_seq, pad_len=8)
+    assert [r.out for r in reqs_p] == want
 
 
 def test_scheduler_exports_occupancy():
     e = Engine(CFG, PARAMS, max_slots=2, max_seq=32, pad_len=8,
-               steps_per_sync=4, paged=True, block_size=8)
+               steps_per_sync=4, block_size=8)
     assert e.pool_occupancy == 0.0
     for r in _reqs(2, 8):
         e.submit(r)
@@ -187,7 +185,7 @@ def test_balancer_completes_all_requests_paged():
     """End-to-end: paged replicas + balancer drain an adversarial queue;
     pool pressure feeds hunger via can_accept."""
     engines = [Engine(CFG, PARAMS, max_slots=2, max_seq=32, pad_len=8,
-                      steps_per_sync=4, paged=True, block_size=8)
+                      steps_per_sync=4, block_size=8)
                for _ in range(2)]
     bal = GLBReplicaBalancer(engines)
     reqs = _reqs(10, 6)
@@ -218,7 +216,7 @@ def test_pool_writing_calls_donate_their_pools():
     the pools (and prefill the row cache) as donated buffers: what they
     were handed is deleted once they return, and the engine holds only
     the live arrays they returned."""
-    e = Engine(CFG, PARAMS, paged=True, block_size=8, max_slots=2,
+    e = Engine(CFG, PARAMS, block_size=8, max_slots=2,
                max_seq=32, pad_len=8, steps_per_sync=4)
     handed = {"prefill": [], "decode": []}
     _spy(e, "_prefill_paged", handed["prefill"])
@@ -240,18 +238,18 @@ def test_donated_pools_keep_tokens_through_cow_migration_and_radix_hit(
         monkeypatch):
     """On donated pools, a radix-cache hit with its copy-on-write block
     copies and a live migration into a peer's pool give the tokens the
-    contiguous engine gives; the copies and the migration's scatter
-    donate what they were handed."""
+    reference gives; the copies and the migration's scatter donate what
+    they were handed."""
     import repro.serve.engine as engine_mod
 
     kw = dict(max_slots=2, max_seq=64, pad_len=16, steps_per_sync=4)
     prompt = [7, 3, 9, 2, 5, 8, 6, 4, 1, 2, 3, 4]     # 1.5 blocks of 8
     mk = lambda base: [Request(rid=base + i, prompt=list(prompt),
                                max_new=6 + 8 * i) for i in range(2)]
-    want = _contiguous_baseline(lambda: mk(0), **kw)
-    e0 = Engine(CFG, PARAMS, paged=True, block_size=8, prefix_cache=True,
+    want = _reference(lambda: mk(0), **kw)
+    e0 = Engine(CFG, PARAMS, block_size=8, prefix_cache=True,
                 **kw)
-    e1 = Engine(CFG, PARAMS, paged=True, block_size=8, **kw)
+    e1 = Engine(CFG, PARAMS, block_size=8, **kw)
     copied, scattered = [], []
     _spy(e0, "_copy_block", copied)
     scatter = engine_mod._scatter_kv
